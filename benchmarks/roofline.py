@@ -71,7 +71,7 @@ class SegCost:
 def _compile_cost(fn, args, in_shardings, mesh, donate=()) -> SegCost:
     import jax
     from repro.launch.dryrun import collective_bytes
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=in_shardings,
                           donate_argnums=donate).lower(*args)
         compiled = lowered.compile()

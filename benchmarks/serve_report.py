@@ -242,7 +242,7 @@ def _engine_section(smoke: bool) -> dict:
     step_batch = {"tokens": prompts[:, :1].astype(jnp.int32)}
 
     def raw_step():
-        with eng.mesh:
+        with jax.set_mesh(eng.mesh):
             return eng.timer.run("decode", eng._decode, eng.params, cache,
                                  step_batch)
 

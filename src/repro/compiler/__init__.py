@@ -216,11 +216,8 @@ def _trace_state_clean() -> bool:
     inside a trace: the candidate executions there are re-traced per call
     (orders of magnitude slower) and the recorded timings are meaningless,
     yet would be persisted as a cross-process plan."""
-    try:
-        from jax import core as _core
-        return bool(_core.trace_state_clean())
-    except Exception:  # pragma: no cover — future jax API drift
-        return True
+    from jax import core
+    return core.trace_ctx.is_top_level()
 
 
 def _measure_inputs(graph: Graph) -> Dict[str, np.ndarray]:

@@ -108,22 +108,25 @@ def graph_fingerprint(g: Graph) -> str:
     return hashlib.sha256(blob.encode()).hexdigest()
 
 
+def env_fingerprint(version: str, platform: str, device_kind: str) -> str:
+    """Toolchain + device identity folded into every request key.
+    Measured-runtime plans (``autotune='measure'``) are only as good as the
+    jax build and the device that timed them — a pump winner measured on
+    the CPU, or on another chip kind, must never replay on this one, so an
+    upgrade or a device change degrades to a cold re-measure instead."""
+    return f"jax-{version}/{platform}/{device_kind}"
+
+
 def _env_fingerprint() -> str:
-    """Toolchain identity folded into every request key.  Measured-runtime
-    plans (``autotune='measure'``) are only as good as the jax build that
-    timed them — a winner measured under one version must not be silently
-    replayed under another, so the jax version is part of the key and an
-    upgrade degrades to a cold re-measure instead of stale replay."""
-    try:
-        import jax
-        return f"jax-{jax.__version__}"
-    except Exception:  # pragma: no cover — jax-free planning contexts
-        return "jax-none"
+    """:func:`env_fingerprint` of this process (its first jax device)."""
+    import jax
+    dev = jax.devices()[0]
+    return env_fingerprint(jax.__version__, dev.platform, dev.device_kind)
 
 
 def request_key(g: Graph, **params) -> str:
     """Cache key for one compile request: structure hash + parameters +
-    toolchain fingerprint (jax version)."""
+    toolchain and device fingerprint (jax version, platform, device kind)."""
     blob = json.dumps([graph_fingerprint(g), _env_fingerprint(),
                        sorted(params.items())],
                       sort_keys=True, default=repr)

@@ -65,6 +65,7 @@ import jax.numpy as jnp
 from repro import obs
 from repro.core.executor import _toposort
 from repro.core.ir import CarrySpec, Graph, NodeKind
+from repro.core.pump_plan import VMEM_BYTES
 from repro.core.symbolic import (Affine, BlockedAccess, blocked_access,
                                  narrow_block, split_temporal)
 from repro.testing import faults
@@ -507,6 +508,83 @@ def _block_unit_ok(plan: RegionPlan) -> bool:
                 for a in plan.blocks.values())
 
 
+# ----------------------------------------------------------- TPU tiling --
+@dataclasses.dataclass(frozen=True)
+class TileView:
+    """A free (row-major) reshape of one memory under which its block obeys
+    the TPU tiling rule: the last block dim a multiple of 128 or the whole
+    dim, the second-to-last a multiple of 8 or the whole dim (rank-1 blocks:
+    a multiple of XLA's 1-D tile or the whole array).
+
+    ``kind`` says how block-unit offsets map into the view:
+    ``same`` (no reshape), ``unit`` (a unit axis inserted before the last
+    dim, so a ``(…, 1, X)`` block becomes ``(…, 1, 1, X)`` — the kernel's
+    reshape back only touches leading dims) and ``flat`` (a rank-1 memory
+    of ``n`` elements in blocks of ``b`` viewed as ``(n // b, 1, b)``)."""
+
+    shape: Tuple[int, ...]
+    block: Tuple[int, ...]
+    kind: str = "same"
+
+    def index(self, offs: Tuple) -> Tuple:
+        if self.kind == "unit":
+            return tuple(offs[:-1]) + (0,) + tuple(offs[-1:])
+        if self.kind == "flat":
+            return (offs[0], 0, 0)
+        return tuple(offs)
+
+
+def _tiling_ok(shape: Tuple[int, ...], block: Tuple[int, ...],
+               itemsize: int) -> bool:
+    if len(block) == 1:
+        # XLA lays a rank-1 array out in tiles of 1024 32-bit words, and the
+        # kernel's operand layout must match it
+        return block[0] == shape[0] or block[0] % (1024 * (4 // itemsize
+                                                           or 1)) == 0
+    return ((block[-1] == shape[-1] or block[-1] % 128 == 0)
+            and (block[-2] == shape[-2] or block[-2] % 8 == 0))
+
+
+def tile_view(shape: Tuple[int, ...], block: Tuple[int, ...],
+              itemsize: int) -> Optional[TileView]:
+    """The :class:`TileView` that makes ``block`` legal on TPU, or None when
+    no unit-axis reshape can (e.g. a last block dim that is a sub-lane
+    slice of a longer dim)."""
+    shape, block = tuple(shape), tuple(block)
+    if _tiling_ok(shape, block, itemsize):
+        return TileView(shape, block)
+    if len(block) == 1:
+        n, b = shape[0], block[0]
+        return TileView((n // b, 1, b), (1, 1, b), "flat") \
+            if n % b == 0 else None
+    if block[-2] == 1 and (block[-1] == shape[-1] or block[-1] % 128 == 0):
+        return TileView(shape[:-1] + (1, shape[-1]),
+                        block[:-1] + (1, block[-1]), "unit")
+    return None
+
+
+def _views(g: Graph, plan: RegionPlan):
+    """(operand views keyed like ``plan.blocks``, output views) — None for
+    any access no :class:`TileView` legalizes."""
+    def view(mem: str, ba: BlockedAccess) -> Optional[TileView]:
+        node = g.nodes[mem]
+        return tile_view(node.shape, ba.block, np.dtype(node.dtype).itemsize)
+
+    ins = {key: view(plan.region.bindings[key[0]][key[1]][1], acc)
+           for key, acc in plan.blocks.items()}
+    outs = [view(mem, ba) for _c, mem, ba in plan.outputs]
+    return ins, outs
+
+
+def tpu_tiling_ok(g: Graph, plan: RegionPlan) -> bool:
+    """True when every operand and output block of ``plan`` can be laid out
+    legally for the TPU compiler (the real-chip half of pallas
+    expressibility; interpret mode has no tiling rule)."""
+    ins, outs = _views(g, plan)
+    return all(v is not None for v in ins.values()) \
+        and all(v is not None for v in outs)
+
+
 # ---------------------------------------------------------------- emission --
 def _affine_eval(a: Affine, env: Mapping[str, Any]):
     out = a.const
@@ -679,13 +757,22 @@ def emit_pallas(g: Graph, plan: RegionPlan, interpret: bool) -> Callable:
     """Tier ``pallas``: one ``pl.pallas_call`` for the whole region, block
     specs and index maps derived from the symbolic access patterns.  Carry
     plans keep their state in VMEM scratch with ``pl.when``-gated sweep
-    init/finalize — the hand-written flash-attention schedule, derived."""
+    init/finalize — the hand-written flash-attention schedule, derived.
+
+    Every memory is passed through its :class:`TileView`, so the block specs
+    obey the TPU tiling rule; the kernel body reshapes each view block back
+    to the access's own block shape, so tile bodies never see the view.
+    Accesses no view legalizes keep their raw block (interpret mode only —
+    :func:`lower_pallas` routes such plans off this tier on a chip).  The
+    scoped VMEM limit is the budget the pump planner plans against."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     grid_sizes = tuple(e for _, e in plan.grid)
     syms = [s for s, _ in plan.grid]
     red_axes = [i for i, (s, _) in enumerate(plan.grid)
                 if s in plan.reduce_syms]
+    params = pltpu.CompilerParams(vmem_limit_bytes=VMEM_BYTES)
 
     mem_order: List[Tuple[str, int]] = []    # (compute, operand idx), flat
     for c in plan.region.computes:
@@ -693,7 +780,31 @@ def emit_pallas(g: Graph, plan: RegionPlan, interpret: bool) -> Callable:
             if src[0] == "mem":
                 mem_order.append((c, k))
 
-    def index_map_for(acc: BlockedAccess):
+    in_views, out_views = _views(g, plan)
+    for key in mem_order:
+        if in_views[key] is None:
+            mem = g.nodes[plan.region.bindings[key[0]][key[1]][1]]
+            in_views[key] = TileView(mem.shape, plan.blocks[key].block)
+    out_views = [v if v is not None
+                 else TileView(g.nodes[mem].shape, ba.block)
+                 for v, (_c, mem, ba) in zip(out_views, plan.outputs)]
+
+    def load(ref, acc: BlockedAccess):
+        """One operand block, in the access's own block shape."""
+        return jnp.reshape(ref[...], acc.block)
+
+    def store(ref, val) -> None:
+        ref[...] = jnp.reshape(val, ref.shape).astype(ref.dtype)
+
+    def operands(mems):
+        return [jnp.reshape(mems[plan.region.bindings[c][k][1]],
+                            in_views[(c, k)].shape) for c, k in mem_order]
+
+    def results(outs) -> Dict[str, Any]:
+        return {mem: jnp.reshape(o, g.nodes[mem].shape)
+                for (_c, mem, _ba), o in zip(plan.outputs, outs)}
+
+    def index_map_for(acc: BlockedAccess, view: TileView):
         offs = acc.block_unit_offsets()
 
         def eval_scalar(a: Affine, env):
@@ -712,24 +823,28 @@ def emit_pallas(g: Graph, plan: RegionPlan, interpret: bool) -> Callable:
 
         def index_map(*gids):
             env = dict(zip(syms, gids))
-            return tuple(eval_scalar(a, env) for a in offs)
+            return view.index(tuple(eval_scalar(a, env) for a in offs))
 
         return index_map
 
-    in_specs = [pl.BlockSpec(plan.blocks[key].block,
-                             index_map_for(plan.blocks[key]))
+    in_specs = [pl.BlockSpec(in_views[key].block,
+                             index_map_for(plan.blocks[key], in_views[key]))
                 for key in mem_order]
-    out_specs = [pl.BlockSpec(ba.block, index_map_for(ba))
-                 for _c, _m, ba in plan.outputs]
-    out_shapes = [jax.ShapeDtypeStruct(g.nodes[mem].shape,
-                                       g.nodes[mem].dtype)
-                  for _c, mem, _ba in plan.outputs]
-    mems_order = [mem for _c, mem, _ba in plan.outputs]
+    out_specs = [pl.BlockSpec(v.block, index_map_for(ba, v))
+                 for v, (_c, _m, ba) in zip(out_views, plan.outputs)]
+    out_shapes = [jax.ShapeDtypeStruct(v.shape, g.nodes[mem].dtype)
+                  for v, (_c, mem, _ba) in zip(out_views, plan.outputs)]
     n_out = len(plan.outputs)
+    in_accs = [plan.blocks[key] for key in mem_order]
+
+    def call(kernel, mems, **kw):
+        outs = pl.pallas_call(
+            kernel, grid=grid_sizes, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shapes, compiler_params=params,
+            interpret=interpret, **kw)(*operands(mems))
+        return results(outs)
 
     if plan.carry is not None:
-        from jax.experimental.pallas import tpu as pltpu
-
         spec = plan.carry
         n_step_out = spec.n_step_outs(n_out)
         state_shapes = []
@@ -759,40 +874,22 @@ def emit_pallas(g: Graph, plan: RegionPlan, interpret: bool) -> Callable:
                 for ref, fill in zip(st_refs, fills):
                     ref[...] = jnp.full(ref.shape, fill, ref.dtype)
 
-            blocks = [r[...] for r in in_refs]
+            blocks = [load(r, a) for r, a in zip(in_refs, in_accs)]
             carry = tuple(r[...] for r in st_refs)
             carry2, souts = spec.step_fn(carry, *blocks, **kwargs)
             for ref, val in zip(st_refs, carry2):
                 ref[...] = val
             for k in range(n_step_out):
-                out_refs[k][...] = jnp.reshape(
-                    souts[f"out{k}"],
-                    plan.outputs[k][2].block).astype(out_refs[k].dtype)
+                store(out_refs[k], souts[f"out{k}"])
             if spec.final_fn is not None:
                 fouts = spec.final_fn(carry2)
 
                 @pl.when(last)
                 def _finish():
                     for k in range(n_step_out, n_out):
-                        out_refs[k][...] = jnp.reshape(
-                            fouts[f"out{k}"],
-                            plan.outputs[k][2].block).astype(
-                                out_refs[k].dtype)
+                        store(out_refs[k], fouts[f"out{k}"])
 
-        def region_fn(mems: Dict[str, Any]) -> Dict[str, Any]:
-            args = [mems[plan.region.bindings[c][k][1]] for c, k in mem_order]
-            outs = pl.pallas_call(
-                kernel,
-                grid=grid_sizes,
-                in_specs=in_specs,
-                out_specs=out_specs,
-                out_shape=out_shapes,
-                scratch_shapes=scratch_shapes,
-                interpret=interpret,
-            )(*args)
-            return dict(zip(mems_order, outs))
-
-        return region_fn
+        return lambda mems: call(kernel, mems, scratch_shapes=scratch_shapes)
 
     if n_out > 1:
         # multi-output map: no reduction symbols (plan construction), every
@@ -802,32 +899,21 @@ def emit_pallas(g: Graph, plan: RegionPlan, interpret: bool) -> Callable:
 
         def kernel(*refs):
             in_refs, out_refs = refs[:len(mem_order)], refs[len(mem_order):]
-            blocks = {key: r[...] for key, r in zip(mem_order, in_refs)}
+            blocks = {key: load(r, a)
+                      for key, r, a in zip(mem_order, in_refs, in_accs)}
             r = plan.tile_fns[comp](
                 **{f"in{k}": blocks[(comp, k)] for k in range(n_ops)})
             for k, ref in enumerate(out_refs):
-                ref[...] = jnp.reshape(
-                    r[f"out{k}"], plan.outputs[k][2].block).astype(ref.dtype)
+                store(ref, r[f"out{k}"])
 
-        def region_fn(mems: Dict[str, Any]) -> Dict[str, Any]:
-            args = [mems[plan.region.bindings[c][k][1]] for c, k in mem_order]
-            outs = pl.pallas_call(
-                kernel,
-                grid=grid_sizes,
-                in_specs=in_specs,
-                out_specs=out_specs,
-                out_shape=out_shapes,
-                interpret=interpret,
-            )(*args)
-            return dict(zip(mems_order, outs))
-
-        return region_fn
+        return lambda mems: call(kernel, mems)
 
     def kernel(*refs):
-        in_refs, o_ref = refs[:-1], refs[-1]
-        blocks = {key: r[...] for key, r in zip(mem_order, in_refs)}
+        in_refs, (o_ref,) = refs[:-1], refs[-1:]
+        blocks = {key: load(r, a)
+                  for key, r, a in zip(mem_order, in_refs, in_accs)}
         tile = _run_tiles(plan, lambda c, k: blocks[(c, k)])
-        tile = jnp.reshape(tile, plan.out_block.block).astype(o_ref.dtype)
+        tile = jnp.reshape(tile, o_ref.shape).astype(o_ref.dtype)
         if red_axes:
             first = functools.reduce(
                 jnp.logical_and, [pl.program_id(a) == 0 for a in red_axes])
@@ -842,19 +928,7 @@ def emit_pallas(g: Graph, plan: RegionPlan, interpret: bool) -> Callable:
         else:
             o_ref[...] = tile
 
-    def region_fn(mems: Dict[str, Any]) -> Dict[str, Any]:
-        args = [mems[plan.region.bindings[c][k][1]] for c, k in mem_order]
-        out = pl.pallas_call(
-            kernel,
-            grid=grid_sizes,
-            in_specs=in_specs,
-            out_specs=out_specs[0],
-            out_shape=out_shapes[0],
-            interpret=interpret,
-        )(*args)
-        return {mems_order[0]: out}
-
-    return region_fn
+    return lambda mems: call(kernel, mems)
 
 
 def emit_gather(g: Graph, region: Region) -> Callable:
@@ -952,6 +1026,12 @@ def lower_pallas(g: Graph, jit: bool = True, pallas_mode: str = "auto",
     for region in regions:
         notes: List[str] = []
         plan = plan_region(g, region, notes.append)
+        if plan is not None and use_pallas and plan.pallas_ok \
+                and not interpret and not tpu_tiling_ok(g, plan):
+            notes.append(f"region {region.name}: a block breaks the TPU "
+                         "tiling rule under every unit-axis view; not "
+                         "emitted as a pallas kernel")
+            plan.pallas_ok = False
         for n in notes:
             warn(n)
         if plan is not None and use_pallas and plan.pallas_ok:

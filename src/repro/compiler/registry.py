@@ -400,7 +400,7 @@ class PlanRegistry:
         itemsize = jnp.dtype(dtype).itemsize
         args = (bb, h, sb, tb, d)
         kwargs = dict(bq=bq_e, bkv=bkv_e, hkv=hkv, causal=causal,
-                      dtype=dtype, itemsize=itemsize)
+                      dtype=dtype, itemsize=itemsize, stats=False)
         return args, kwargs, (bb, sb, tb)
 
     def ssd_request(self, *, b: int, l: int, h: int, p: int, n: int,

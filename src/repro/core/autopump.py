@@ -54,6 +54,15 @@ def _xp(a):
     return jnp
 
 
+def _iota(xp, shape, dim: int):
+    """int32 index grid along ``dim`` of a 2-D ``shape`` — the TPU kernel
+    compiler has no 1-D iota, so tile bodies build positions this way."""
+    if xp is np:
+        return np.indices(shape, dtype=np.int32)[dim]
+    import jax
+    return jax.lax.broadcasted_iota(np.int32, shape, dim)
+
+
 # ------------------------------------------------------------ IR builders --
 # fn bodies are numpy/jax polymorphic (operator-based) so the same body runs
 # in the reference executor and in the compiler's lowering backends.  The
@@ -196,11 +205,21 @@ def _floyd_graph(n: int, itemsize: int = 4):
     acc = AccessPattern(dom, (Affine.of("r"), Affine.constant(0)), width=n)
 
     def fn(in0):
-        xp = _xp(in0)
         d = in0.reshape(n, n)
-        for k in range(n):
-            d = xp.minimum(d, d[:, k:k + 1] + d[k:k + 1, :])
-        return {"out0": d.reshape(-1)}
+        if _xp(in0) is np:
+            for k in range(n):
+                d = np.minimum(d, d[:, k:k + 1] + d[k:k + 1, :])
+            return {"out0": d.reshape(-1)}
+        # traced, the pivots stay one loop: unrolled, 256 of them took
+        # minutes to compile for a TPU
+        import jax
+
+        def relax(k, d):
+            col = jax.lax.dynamic_slice_in_dim(d, k, 1, axis=1)
+            row = jax.lax.dynamic_slice_in_dim(d, k, 1, axis=0)
+            return jax.numpy.minimum(d, col + row)
+
+        return {"out0": jax.lax.fori_loop(0, n, relax, d).reshape(-1)}
 
     g.compute("relax", dom, fn=fn, vector_width=max(n // 128, 4),
               data_dependent_io=False)
@@ -225,13 +244,19 @@ def _blk(sym: str, size: int, nblocks: int) -> Affine:
 def _flash_graph(b: int, h: int, s: int, t: int, d: int, bq: int = 128,
                  bkv: int = 128, itemsize: int = 2, hkv: Optional[int] = None,
                  causal: bool = False, scale: Optional[float] = None,
-                 dtype: str = "float32", vector_width: Optional[int] = None):
+                 dtype: str = "float32", vector_width: Optional[int] = None,
+                 stats: bool = True):
     """Flash attention as an executable carry graph.
 
     The online-softmax recurrence over KV blocks is the sequential-carry
     axis (``ji``); the compute is *multi-output* — the attention tile plus
     its running max and denominator land in three memories (``o``, ``m``,
-    ``l``).  GQA head folding is a group-indexed table on the KV head dim.
+    ``l``).  The row statistics keep a trailing unit axis, ``(b, h, s, 1)``:
+    a ``(bq, 1)`` block of them is TPU-tileable and is exactly the carried
+    ``(bq, 1)`` state, so the kernel stores it without a relayout.  In HBM
+    that unit lane pads to 128, so ``stats=False`` (the serving path, which
+    reads only ``o``) drops ``m`` and ``l``.  GQA head folding is a
+    group-indexed table on the KV head dim.
     """
     hkv = hkv or h
     g = Graph("flash_attention")
@@ -239,8 +264,9 @@ def _flash_graph(b: int, h: int, s: int, t: int, d: int, bq: int = 128,
     g.memory("k", (b, hkv, t, d), dtype=dtype)
     g.memory("v", (b, hkv, t, d), dtype=dtype)
     g.memory("o", (b, h, s, d), dtype=dtype)
-    g.memory("m", (b, h, s))
-    g.memory("l", (b, h, s))
+    if stats:
+        g.memory("m", (b, h, s, 1))
+        g.memory("l", (b, h, s, 1))
     bq, bkv = min(bq, s), min(bkv, t)
     if scale is None:
         scale = d ** -0.5
@@ -287,8 +313,8 @@ def _flash_graph(b: int, h: int, s: int, t: int, d: int, bq: int = 128,
                                   _blk("qi", bq, nq) + Affine.of("r"),
                                   Affine.constant(0)), width=d)
     acc_ml = AccessPattern(dom_o, (Affine.of("bi"), Affine.of("hi"),
-                                   _blk("qi", bq, nq) + Affine.of("r")),
-                           width=1)
+                                   _blk("qi", bq, nq) + Affine.of("r"),
+                                   Affine.constant(0)), width=1)
 
     def step_fn(carry, q_blk, k_blk, v_blk, idx=None):
         xp = _xp(q_blk)
@@ -300,8 +326,8 @@ def _flash_graph(b: int, h: int, s: int, t: int, d: int, bq: int = 128,
         sc = (q2 * f32(scale)) @ k2.T                       # (bq', bkv)
         if causal:
             q_pos = idx["outer"][2] * bq + idx["pump"] * q2.shape[0] \
-                + xp.arange(q2.shape[0])[:, None]
-            k_pos = idx["step"] * bkv + xp.arange(k2.shape[0])[None, :]
+                + _iota(xp, sc.shape, 0)
+            k_pos = idx["step"] * bkv + _iota(xp, sc.shape, 1)
             sc = xp.where(q_pos >= k_pos, sc, f32(NEG_INF))
         m_new = xp.maximum(m_run, sc.max(axis=-1, keepdims=True))
         alpha = xp.exp(m_run - m_new)
@@ -315,10 +341,13 @@ def _flash_graph(b: int, h: int, s: int, t: int, d: int, bq: int = 128,
         m_run, l_run, acc = carry
         l_safe = xp.where(l_run == 0.0, xp.float32(1.0), l_run)
         o_blk = acc / l_safe
+        if not stats:
+            return {"out0": o_blk[None, None]}
         return {"out0": o_blk[None, None],            # (1, 1, bq', d)
-                "out1": m_run[None, None, :, 0],      # (1, 1, bq')
-                "out2": l_run[None, None, :, 0]}
+                "out1": m_run[None, None],            # (1, 1, bq', 1)
+                "out2": l_run[None, None]}
 
+    outs = ({2: "q", 3: "d"},) + (({2: "q"}, {2: "q"}) if stats else ())
     g.compute(
         "online_softmax", dom, vector_width=vector_width,
         carry=CarrySpec(
@@ -327,15 +356,16 @@ def _flash_graph(b: int, h: int, s: int, t: int, d: int, bq: int = 128,
                    ((bq, d), "float32")),
             step_fn=step_fn, final_fn=final_fn, pass_idx=True),
         axes=dict(ins=({2: "q", 3: "d"}, {2: "kv", 3: "d"}, {2: "kv", 3: "d"}),
-                  outs=({2: "q", 3: "d"}, {2: "q"}, {2: "q"}),
+                  outs=outs,
                   carry=({0: "q"}, {0: "q"}, {0: "q", 1: "d"}),
                   narrow="q"))
     g.connect("q", "online_softmax", acc_q)
     g.connect("k", "online_softmax", acc_kv)
     g.connect("v", "online_softmax", acc_kv)
     g.connect("online_softmax", "o", acc_o)
-    g.connect("online_softmax", "m", acc_ml)
-    g.connect("online_softmax", "l", acc_ml)
+    if stats:
+        g.connect("online_softmax", "m", acc_ml)
+        g.connect("online_softmax", "l", acc_ml)
     return g, est
 
 
@@ -524,8 +554,8 @@ def _decode_attention_graph(b: int, h: int, t: int, d: int, bkv: int = 128,
         k2 = k_blk.reshape(k_blk.shape[-2], k_blk.shape[-1]).astype(f32)
         v2 = v_blk.reshape(v_blk.shape[-2], v_blk.shape[-1]).astype(f32)
         sc = (q2 * f32(scale)) @ k2.T                      # (1, bkv)
-        k_pos = idx["step"] * bkv + xp.arange(k2.shape[0])[None, :]
-        sc = xp.where(k_pos <= pos_blk.reshape(-1)[0], sc, f32(NEG_INF))
+        k_pos = idx["step"] * bkv + _iota(xp, sc.shape, 1)
+        sc = xp.where(k_pos <= pos_blk.reshape(1, 1), sc, f32(NEG_INF))
         m_new = xp.maximum(m_run, sc.max(axis=-1, keepdims=True))
         alpha = xp.exp(m_run - m_new)
         prob = xp.exp(sc - m_new)

@@ -2,8 +2,10 @@
 
 Responsibilities: shape padding to block multiples, dtype policy, automatic
 pump-factor planning (``pump='auto'`` asks the capacity model, ``'measure'``
-times candidates), and the interpret/compile switch (CPU container validates
-with interpret=True; on TPU pass interpret=False).
+times candidates), and the interpret/compile switch: ``interpret=None`` (the
+default) runs the Pallas interpreter exactly when no TPU is attached, so a
+chip only ever runs compiled kernels unless a caller asks for interpret
+mode by name.
 
 Flash attention, the SSD scan and grouped GEMM are **compiled, not
 hand-scheduled**: their default path builds the kernel's executable IR graph
@@ -11,8 +13,8 @@ hand-scheduled**: their default path builds the kernel's executable IR graph
 ``repro.compiler.compile(backend='pallas')`` — the fused-region emission
 derives the BlockSpecs, carry scratch and pump schedule that the hand-wired
 Pallas kernels in this package previously encoded by hand.  The hand-wired
-kernels remain as a differential reference and as the fallback
-(``impl='pallas'`` or any compiler-route failure, which warns visibly).
+kernels remain as a differential reference behind ``impl='pallas'``; a
+compiler-route failure raises rather than switching to them.
 
 The decode hot path is compiler-only: :func:`decode_attention` (S=1 against
 a preallocated KV cache, position-offset mask from an int32 ``pos`` input),
@@ -24,7 +26,6 @@ through the plan registry's pos-bucketed wrappers.
 from __future__ import annotations
 
 import functools
-import warnings
 from typing import Optional
 
 import jax
@@ -102,6 +103,11 @@ def _on_accelerator() -> bool:
     return any(d.platform == "tpu" for d in jax.devices())
 
 
+def _resolve_interpret(interpret: Optional[bool]) -> bool:
+    """``None`` means interpret mode exactly when no TPU is attached."""
+    return not _on_accelerator() if interpret is None else bool(interpret)
+
+
 def _use_compiler_route(impl: str, interpret: bool) -> bool:
     """The compiler route serves CPU validation (its carryloop/blockloop jit
     tiers) and real TPU emission.  ``interpret=False`` on CPU is an explicit
@@ -155,9 +161,10 @@ def _vecadd(x, y, vector_width, pump_factor, pump_mode, interpret):
 
 
 def vecadd(x, y, *, vector_width: int = 8, pump: PumpSpec | int | str = 1,
-           interpret: bool = True):
+           interpret: Optional[bool] = None):
     """``pump``: factor, PumpSpec, ``'auto'`` (capacity model) or
     ``'measure'`` (timed on the compiled IR graph, cached)."""
+    interpret = _resolve_interpret(interpret)
     spec = _as_spec(pump, kernel="vecadd", builder_args=(x.shape[0],),
                     builder_kwargs=dict(vector_width=vector_width),
                     block_bytes_in=2 * vector_width * x.dtype.itemsize,
@@ -182,9 +189,10 @@ def _matmul(a, b, bm, bn, bk, pump_factor, pump_mode, interpret):
 
 
 def matmul(a, b, *, bm: int = 128, bn: int = 128, bk: int = 128,
-           pump: PumpSpec | int | str = 1, interpret: bool = True):
+           pump: PumpSpec | int | str = 1, interpret: Optional[bool] = None):
     """``pump``: factor, PumpSpec, ``'auto'`` (capacity model) or
     ``'measure'`` (timed on the compiled IR graph, cached)."""
+    interpret = _resolve_interpret(interpret)
     spec = _as_spec(
         pump, kernel="matmul",
         builder_args=(a.shape[0], b.shape[1], a.shape[1]),
@@ -204,7 +212,8 @@ def _stencil(x, stages, kind, coef, pump_factor, interpret):
 
 
 def stencil_chain(x, stages: int, *, kind: str = "jacobi", coef: float = 0.1,
-                  pump: PumpSpec | int = 1, interpret: bool = True):
+                  pump: PumpSpec | int = 1, interpret: Optional[bool] = None):
+    interpret = _resolve_interpret(interpret)
     f = pump.factor if isinstance(pump, PumpSpec) else pump
     if (x.shape[0] - 2) % f:
         raise ValueError("interior plane count must divide the pump factor")
@@ -217,7 +226,9 @@ def _fw_run(d, pump_factor, interpret):
     return _fw.floyd_warshall_pallas(d, pump=pump_factor, interpret=interpret)
 
 
-def floyd_warshall(dist, *, pump: PumpSpec | int = 1, interpret: bool = True):
+def floyd_warshall(dist, *, pump: PumpSpec | int = 1,
+                   interpret: Optional[bool] = None):
+    interpret = _resolve_interpret(interpret)
     f = pump.factor if isinstance(pump, PumpSpec) else pump
     n = dist.shape[0]
     if n % f:
@@ -256,14 +267,14 @@ def _flash_compiled(q, k, v, causal, bq, bkv, pump):
     kern = _compile_kernel(
         "flash_attention", (b, hq, qp.shape[2], t, d),
         dict(bq=bq, bkv=bkv, hkv=hkv, causal=causal, dtype=str(q.dtype),
-             itemsize=q.dtype.itemsize), pump)
+             itemsize=q.dtype.itemsize, stats=False), pump)
     out = kern({"q": qp, "k": k, "v": v})["o"]
     return out[:, :, :s0, :]
 
 
 def flash_attention(q, k, v, *, causal: bool = False, bq: int = 128,
                     bkv: int = 128, pump: PumpSpec | int | str = 1,
-                    interpret: bool = True, impl: str = "compiler"):
+                    interpret: Optional[bool] = None, impl: str = "compiler"):
     """Multi-head attention (GQA folded via a group-indexed table).
 
     ``impl='compiler'`` (default) compiles the executable IR builder through
@@ -271,13 +282,9 @@ def flash_attention(q, k, v, *, causal: bool = False, bq: int = 128,
     schedule are all derived; ``impl='pallas'`` forces the hand-wired kernel
     (kept as the differential reference).  ``interpret=False`` on CPU keeps
     the hand-wired path's loud failure semantics."""
+    interpret = _resolve_interpret(interpret)
     if _use_compiler_route(impl, interpret):
-        try:
-            return _flash_compiled(q, k, v, causal, bq, bkv, pump)
-        except Exception as e:
-            warnings.warn(f"flash_attention: compiler route failed ({e}); "
-                          "falling back to the hand-wired kernel",
-                          stacklevel=2)
+        return _flash_compiled(q, k, v, causal, bq, bkv, pump)
     d = q.shape[-1]
     spec = _as_spec(pump,
                     block_bytes_in=2 * bkv * d * q.dtype.itemsize,
@@ -313,7 +320,7 @@ def _ssd_compiled(x, dt, A, B, C, chunk, pump, final_state=False):
 
 
 def ssd_scan(x, dt, A, B, C, *, chunk: int = 16,
-             pump: PumpSpec | int | str = 1, interpret: bool = True,
+             pump: PumpSpec | int | str = 1, interpret: Optional[bool] = None,
              impl: str = "compiler", final_state: bool = False):
     """Mamba-2 SSD chunked scan.  ``impl='compiler'`` (default) compiles the
     carry-graph IR builder; ``impl='pallas'`` forces the hand-wired kernel
@@ -321,14 +328,9 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 16,
     final inter-chunk state (B, H, N, P) as a second output — the carry
     state surfaced through ``CarrySpec.final_fn``; compiler-only (the
     hand-wired kernel never exposes its state)."""
+    interpret = _resolve_interpret(interpret)
     if _use_compiler_route(impl, interpret):
-        try:
-            return _ssd_compiled(x, dt, A, B, C, chunk, pump, final_state)
-        except Exception as e:
-            if final_state:
-                raise   # no hand-wired fallback can produce the state
-            warnings.warn(f"ssd_scan: compiler route failed ({e}); falling "
-                          "back to the hand-wired kernel", stacklevel=2)
+        return _ssd_compiled(x, dt, A, B, C, chunk, pump, final_state)
     if final_state:
         raise ValueError("ssd_scan(final_state=True) requires the compiler "
                          "route (impl='compiler')")
@@ -493,8 +495,9 @@ def _grouped_compiled(x, w, bc, bf, bd, pump):
 
 
 def grouped_gemm(x, w, *, bc: int = 128, bf: int = 128, bd: int = 128,
-                 pump: PumpSpec | int | str = 1, interpret: bool = True,
-                 impl: str = "compiler", group_sizes=None):
+                 pump: PumpSpec | int | str = 1,
+                 interpret: Optional[bool] = None, impl: str = "compiler",
+                 group_sizes=None):
     """Per-expert batched GEMM (MoE hot-spot).
 
     Dense form (``group_sizes=None``): x (E,C,D) @ w (E,D,F).
@@ -525,13 +528,9 @@ def grouped_gemm(x, w, *, bc: int = 128, bf: int = 128, bd: int = 128,
         return ragged_grouped_gemm_compiled(
             x, w, sizes, padded, bc_e, min(bf, f), min(bd, d),
             pump=pump if isinstance(pump, (PumpSpec, str)) else int(pump))
+    interpret = _resolve_interpret(interpret)
     if _use_compiler_route(impl, interpret):
-        try:
-            return _grouped_compiled(x, w, bc, bf, bd, pump)
-        except Exception as e:
-            warnings.warn(f"grouped_gemm: compiler route failed ({e}); "
-                          "falling back to the hand-wired kernel",
-                          stacklevel=2)
+        return _grouped_compiled(x, w, bc, bf, bd, pump)
     spec = _as_spec(pump,
                     block_bytes_in=(bc * bd + bd * bf) * x.dtype.itemsize,
                     block_bytes_out=0,

@@ -83,7 +83,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     optcfg = optim.AdamWConfig(moment_dtype="bfloat16")
     t0 = time.time()
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             step = steps_mod.make_train_step(cfg, optcfg, pump_factor)
             in_sh, out_sh, args = steps_mod.train_shardings(

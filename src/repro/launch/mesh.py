@@ -8,22 +8,32 @@ Axes:
 
 Functions, not module constants: importing this module never touches jax
 device state (the dry-run sets XLA_FLAGS before first jax init).
+
+Every axis is ``Auto``: the sharding rules in ``launch/sharding.py`` are
+GSPMD annotations that the partitioner propagates, not sharding-in-types.
+Callers install a mesh with ``jax.set_mesh`` so that code inside a trace
+finds it through ``jax.sharding.get_abstract_mesh()``.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh with the same axis names (smoke tests, examples)."""
     n = jax.device_count()
-    return jax.make_mesh((1, n), ("data", "model"))
+    return _mesh((1, n), ("data", "model"))
 
 
 def mesh_axis_sizes(mesh) -> dict:

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.configs.base import load_arch
+from repro.launch import compile_cache
 from repro.models import model as model_mod
 from repro.serve.engine import Engine, ServeConfig
 
@@ -94,6 +95,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="capture a jax.profiler trace of generate() to DIR")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.trace:
         obs.enable()

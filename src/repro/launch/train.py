@@ -15,7 +15,7 @@ import jax
 
 from repro import optim
 from repro.configs.base import SHAPES, ShapeConfig, load_arch
-from repro.launch import mesh as mesh_mod
+from repro.launch import compile_cache, mesh as mesh_mod
 from repro.train.trainer import TrainConfig, train
 
 
@@ -40,6 +40,7 @@ def main(argv=None) -> None:
                     help="seconds without progress before a worker is "
                          "considered dead (--failover)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = load_arch(args.arch, smoke=args.smoke)
     shape = SHAPES[args.shape]

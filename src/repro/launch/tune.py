@@ -50,8 +50,10 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     args = ap.parse_args(argv)
 
     from repro.configs.base import load_arch
+    from repro.launch import compile_cache
     from repro.tune import run_fleet
 
+    compile_cache.enable()
     cfg = load_arch(args.arch, smoke=args.smoke)
     overrides = {k: v for k, v in (("attention_impl", args.attention_impl),
                                    ("ssm_impl", args.ssm_impl)) if v}

@@ -8,8 +8,8 @@ Two execution paths, selected by ``cfg.attention_impl``:
     dependency chain stays sequential.  Memory is O(S·block), so 32k prefill
     lowers without materializing S×S logits.  Differentiable; used by the
     dry-run and trainer.
-  - ``pallas``: the :mod:`repro.kernels.flash_attention` kernel (interpret
-    mode on CPU) — used by smoke tests at small sizes and the TPU target.
+  - ``pallas``: the compiler-emitted flash-attention kernel (interpret
+    mode only when no TPU is attached) — the serving path on the chip.
 
 Decode attends one query token against a preallocated KV cache.  Under
 ``attention_impl='pallas'`` + ``kernel_plan='measure'`` (the serving
@@ -59,7 +59,7 @@ def _kv_valid_mask(length: int, pos, s: int):
     return idx < (pos + s)
 
 
-def _flash_kernel(cfg, q, k, v, *, causal, interpret=True):
+def _flash_kernel(cfg, q, k, v, *, causal):
     """Flash-attention kernel dispatch for the ``pallas`` impl paths.
 
     ``cfg.kernel_plan == 'measure'`` (default) routes through the process
@@ -71,7 +71,7 @@ def _flash_kernel(cfg, q, k, v, *, causal, interpret=True):
         from repro.compiler.registry import default_registry
         return default_registry().flash_attention(q, k, v, causal=causal)
     from repro.kernels.ops import flash_attention as _flash
-    return _flash(q, k, v, causal=causal, interpret=interpret)
+    return _flash(q, k, v, causal=causal)
 
 
 # ------------------------------------------------------------ core attention
@@ -167,7 +167,7 @@ def gqa_init(key, cfg, dtype=jnp.float32):
 
 
 def gqa_apply(p, cfg, x, *, positions, causal=True, cache=None,
-              kv_input=None, interpret=True):
+              kv_input=None):
     """GQA attention.  x: (B, S, d).  Returns (out, new_cache).
 
     ``kv_input`` (B, T, d) switches to cross-attention (no cache, no causal).
@@ -247,8 +247,7 @@ def gqa_apply(p, cfg, x, *, positions, causal=True, cache=None,
             # unknowable here) selects the position-aware chunked branch.
             out = jax.lax.cond(
                 pos == 0,
-                lambda: _flash_kernel(cfg, q, k, v, causal=causal,
-                                      interpret=interpret),
+                lambda: _flash_kernel(cfg, q, k, v, causal=causal),
                 lambda: chunked_attention(q, kc, vc, causal=causal,
                                           q_pos=positions, kv_mask=kv_mask,
                                           block=cfg.attn_block_kv))
@@ -258,7 +257,7 @@ def gqa_apply(p, cfg, x, *, positions, causal=True, cache=None,
                                     q_pos=positions, kv_mask=kv_mask,
                                     block=cfg.attn_block_kv)
     elif cfg.attention_impl == "pallas" and kv_input is None:
-        out = _flash_kernel(cfg, q, k, v, causal=causal, interpret=interpret)
+        out = _flash_kernel(cfg, q, k, v, causal=causal)
     else:
         out = chunked_attention(q, k, v, causal=causal and kv_input is None,
                                 q_pos=positions, block=cfg.attn_block_kv)
@@ -309,8 +308,7 @@ def _mla_q(p, cfg, x):
     return q[..., :dn], q[..., dn:]
 
 
-def mla_apply(p, cfg, x, *, positions, causal=True, cache=None,
-              interpret=True):
+def mla_apply(p, cfg, x, *, positions, causal=True, cache=None):
     """MLA attention.  Prefill/train: decompressed path + chunked flash.
     Decode: absorbed path over the compressed cache."""
     m = cfg.mla
@@ -381,8 +379,7 @@ def mla_apply(p, cfg, x, *, positions, causal=True, cache=None,
             # keeps any pos > 0 continuation on the reference chunked path
             out = jax.lax.cond(
                 pos == 0,
-                lambda: _flash_kernel(cfg, q, k, v, causal=causal,
-                                      interpret=interpret),
+                lambda: _flash_kernel(cfg, q, k, v, causal=causal),
                 lambda: chunked_attention(q, k, v, causal=causal,
                                           q_pos=positions,
                                           block=cfg.attn_block_kv,
@@ -444,7 +441,7 @@ def mla_apply(p, cfg, x, *, positions, causal=True, cache=None,
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     q, k, v = (u.swapaxes(1, 2) for u in (q, k, v))
     if cfg.attention_impl == "pallas" and dn + dr == dv:
-        out = _flash_kernel(cfg, q, k, v, causal=causal, interpret=interpret)
+        out = _flash_kernel(cfg, q, k, v, causal=causal)
     else:
         out = chunked_attention(q, k, v, causal=causal, q_pos=positions,
                                 block=cfg.attn_block_kv, scale=scale)

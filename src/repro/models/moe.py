@@ -36,22 +36,19 @@ def _ep_constraint(arr):
         # operands (+2.2× bytes, +3.5× collectives).  GSPMD's propagated
         # layout matches the unconstrained optimum, so this is opt-in only.
         return arr
-    try:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        env = jax.interpreters.pxla.thread_resources.env
-        mesh = env.physical_mesh
-        if mesh.empty or "model" not in mesh.axis_names:
-            return arr
-        sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        espec = "model" if arr.shape[0] % sizes["model"] == 0 else None
-        cspec = "data" if ("data" in sizes
-                           and arr.shape[1] % sizes["data"] == 0) else None
-        if espec is None and cspec is None:
-            return arr
-        return jax.lax.with_sharding_constraint(
-            arr, NamedSharding(mesh, P(espec, cspec, None)))
-    except Exception:  # noqa: BLE001 — sharding is an optimization only
+    from jax.sharding import PartitionSpec as P
+    # the mesh the caller installed with jax.set_mesh (launch/mesh.py); a
+    # bare PartitionSpec below resolves against it (a GSPMD hint: Auto axes)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.axis_names:
         return arr
+    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
+    espec = "model" if arr.shape[0] % sizes["model"] == 0 else None
+    cspec = "data" if ("data" in sizes
+                       and arr.shape[1] % sizes["data"] == 0) else None
+    if espec is None and cspec is None:
+        return arr
+    return jax.lax.with_sharding_constraint(arr, P(espec, cspec, None))
 
 
 def _ragged_dropless_experts(p, cfg, xt, gate, idx):

@@ -2,7 +2,7 @@
 
 Block structure: in-proj → short causal conv → SSD scan (the temporal-
 vectorization flagship kernel) → gated out-proj.  Two SSD paths selected by
-``cfg.ssm_impl``: ``pallas`` (repro.kernels.ssd_scan, interpret on CPU) and
+``cfg.ssm_impl``: ``pallas`` (the compiled SSD scan kernel) and
 ``xla`` (chunked jnp with a lax.scan over chunks — the same chunked math the
 kernel implements, so the two agree to float tolerance).
 
@@ -126,7 +126,7 @@ def _ssd_xla(x, dt, A, B, C, chunk):
     return y.astype(x.dtype), s_final.reshape(b, h, n, p)
 
 
-def mamba2_apply(p, cfg, x, *, cache=None, interpret=True):
+def mamba2_apply(p, cfg, x, *, cache=None):
     """x: (B, L, d) -> (out, new_cache).  cache: dict(state, conv, pos)."""
     s = cfg.ssm
     b, l, d = x.shape
@@ -216,7 +216,7 @@ def mamba2_apply(p, cfg, x, *, cache=None, interpret=True):
                                                      final_state=True)
         elif cfg.ssm_impl == "pallas" and cache is None:
             from repro.kernels.ops import ssd_scan as _ssd
-            y = _ssd(xh, dt, A, Bg, Cg, chunk=chunk, interpret=interpret)
+            y = _ssd(xh, dt, A, Bg, Cg, chunk=chunk)
             s_final = None
         else:
             y, s_final = _ssd_xla(xh, dt, A, Bg, Cg, chunk)

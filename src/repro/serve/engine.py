@@ -251,7 +251,7 @@ class Engine:
         try:
             faults.check(f"engine.{phase}")
             step_fn = self._cont() if cont else self._decode
-            with self.mesh:
+            with jax.set_mesh(self.mesh):
                 logits, new_cache = self.timer.run(
                     phase, step_fn, self.params, cache, batch)
             if self._nan_guarded() and \
@@ -264,7 +264,7 @@ class Engine:
                       reason=type(e).__name__)
             self._req_degraded = True
             fb = self._fallback_cont() if cont else self._fallback()
-            with self.mesh:
+            with jax.set_mesh(self.mesh):
                 return self.timer.run(phase, fb, self.params, cache, batch)
 
     def prefill(self, tokens: jax.Array, enc_out=None):

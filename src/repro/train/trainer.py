@@ -72,7 +72,7 @@ def make_trainer(cfg: ModelConfig, shape: ShapeConfig,
                      donate_argnums=(0, 1))
 
     def init_fn(key) -> TrainState:
-        with mesh:
+        with jax.set_mesh(mesh):
             params = jax.jit(
                 lambda k: model_mod.init_params(cfg, k, dtype=pdt),
                 out_shardings=in_sh[0])(key)
@@ -82,7 +82,7 @@ def make_trainer(cfg: ModelConfig, shape: ShapeConfig,
         return TrainState(params, opt_state, 0)
 
     def step_fn(state: TrainState, batch) -> tuple:
-        with mesh:
+        with jax.set_mesh(mesh):
             params, opt_state, metrics = jitted(state.params, state.opt_state,
                                                 batch)
         return TrainState(params, opt_state, state.step + 1), metrics

@@ -2,7 +2,7 @@
 
 One JSON file, written atomically (mkstemp+rename), schema-versioned::
 
-    {"schema": 1, "env": "jax-0.4.x", "created": 1723...,
+    {"schema": 1, "env": "jax-0.9.0/tpu/TPU v5 lite", "created": 1723...,
      "complete": false,                       # partial-result salvage
      "entries":  {<content-hash>: <plan dict, as stored in CompileCache>},
      "manifest": {<content-hash>: {"kernel": ..., "sha256": ...,
@@ -12,8 +12,9 @@ One JSON file, written atomically (mkstemp+rename), schema-versioned::
      "missing":  [<content-hash>, ...]}       # enumerated but unmeasured
 
 The manifest is the verification surface: each entry carries the sha256 of
-its canonical-JSON plan and the jax version that measured it, so a replica
-verifies *per entry* — one bitrotted or stale plan is quarantined and
+its canonical-JSON plan and the jax version and device that measured it,
+so a replica verifies *per entry* — one bitrotted or stale plan is
+quarantined and
 re-measured locally while every other entry still loads with zero
 measurements (:meth:`repro.compiler.registry.PlanRegistry.
 preload_artifact`).

@@ -14,8 +14,9 @@ across every backend.  Flash attention, the SSD kernels (scan, the
 final-state variant, the single-token decode step) and decode attention
 contain ``exp``, whose numpy and XLA CPU implementations differ by 1 ULP on
 some inputs, so no backend pair can agree bitwise; those cases assert to a
-1-ULP-amplified tolerance (``rtol=atol=5e-6``) instead — the flash
-running-max output ``m`` (built from max alone) is still checked bit-exact.
+1-ULP-amplified tolerance (``rtol=atol=5e-6``) instead.  That includes the
+flash running-max output ``m``: it is a max over dot products, and XLA's
+CPU dot may round a score 1 ULP away from numpy's.
 
 The sweep axes (``BACKENDS × FACTORS × MODES``) intentionally mirror the
 acceptance contract: every backend must hold for M ∈ {1, 2, 4} in both
@@ -47,7 +48,6 @@ class Case:
     input_shapes: Dict[str, Tuple]    # memory name -> shape
     outputs: Tuple[str, ...]          # memory names to compare
     exact: bool = True                # bit-exact vs executor (see module doc)
-    exact_outputs: Tuple[str, ...] = ()   # bit-exact even when exact=False
     gold: Optional[Callable] = None   # inputs -> {output name: array}
     transform: Optional[Callable] = None  # post-process generated inputs
     seed: int = 0
@@ -150,7 +150,7 @@ def cases(shape_index: int = 0) -> Dict[str, Case]:
                 "flash_attention", (1, 2, 32, 32, 8),
                 dict(bq=16, bkv=8, causal=True, vector_width=8),
                 {"q": (1, 2, 32, 8), "k": (1, 2, 32, 8), "v": (1, 2, 32, 8)},
-                ("o", "m", "l"), exact=False, exact_outputs=("m",),
+                ("o", "m", "l"), exact=False,
                 gold=lambda i: _flash_gold(i, causal=True)),
             "ssd_scan": Case(
                 "ssd_scan", (1, 32, 2, 4, 4), dict(chunk=8, vector_width=8),
@@ -204,7 +204,7 @@ def cases(shape_index: int = 0) -> Dict[str, Case]:
             "flash_attention", (2, 4, 16, 32, 4),
             dict(bq=8, bkv=8, hkv=2, vector_width=8),    # GQA fold
             {"q": (2, 4, 16, 4), "k": (2, 2, 32, 4), "v": (2, 2, 32, 4)},
-            ("o", "m", "l"), exact=False, exact_outputs=("m",),
+            ("o", "m", "l"), exact=False,
             gold=lambda i: _flash_gold(i), seed=1),
         "ssd_scan": Case(
             "ssd_scan", (2, 16, 4, 8, 2),
@@ -260,7 +260,7 @@ def run_case(case: Case, factor: int, mode: str, backend: str,
     gold = executor.run(kern.graph, dict(inputs))
     for name in case.outputs:
         a, b = np.asarray(out[name]), gold[name]
-        if case.exact or name in case.exact_outputs:
+        if case.exact:
             np.testing.assert_array_equal(
                 a, b, err_msg=f"{case.kernel}:{name} vs executor "
                               f"(M={factor} {mode} {backend})")

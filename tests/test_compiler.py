@@ -820,3 +820,56 @@ def test_autopump_routes_through_pipeline(tmp_path):
     assert r2.pipeline_report.served_from in ("memory", "disk")
     assert r2.pipeline_report.cache_hits >= 1
     assert r2.spec == r.spec
+
+
+@pytest.mark.parametrize("kernel", ["decode_attention", "vecadd",
+                                    "ssd_decode", "flash_attention"])
+def test_tile_views_keep_interpret_emission_exact(kernel):
+    """Blocks that break the TPU tiling rule run through unit-axis views of
+    their memories (decode's (1, 1, d) query rows and (1,) positions,
+    vecadd's 8-element blocks); the views must not change a result."""
+    run_case(_DIFF0[kernel], 2, "T", "pallas", pallas_mode="interpret")
+
+
+def test_tile_view_rules():
+    from repro.compiler.pallas_backend import tile_view
+    # a flash q/k/v/o block is legal as it stands
+    assert tile_view((1, 16, 2048, 128), (1, 1, 128, 128), 2).kind == "same"
+    # flash row statistics keep a trailing unit axis: (bq, 1) is legal
+    assert tile_view((1, 16, 2048, 1), (1, 1, 128, 1), 4).kind == "same"
+    # one decode query row per (batch, head): a unit axis before the last
+    v = tile_view((4, 16, 128), (1, 1, 128), 2)
+    assert (v.kind, v.shape, v.block) == \
+        ("unit", (4, 16, 1, 128), (1, 1, 1, 128))
+    assert v.index((3, 5, 0)) == (3, 5, 0, 0)
+    # one decode position per batch row
+    v = tile_view((4,), (1,), 4)
+    assert (v.kind, v.shape, v.block) == ("flat", (4, 1, 1), (1, 1, 1))
+    assert v.index((3,)) == (3, 0, 0)
+    # rank-1 blocks must match XLA's 1024-word tile or cover the array
+    assert tile_view((1 << 20,), (1024,), 4).kind == "same"
+    assert tile_view((1 << 20,), (512,), 4).kind == "flat"
+    # a sub-lane slice of the last dim has no legal view (mode-R decode)
+    assert tile_view((4, 8, 256, 128), (1, 1, 128, 64), 2) is None
+
+
+def test_env_fingerprint_keys_on_device_kind(tmp_path, monkeypatch):
+    """A pump winner measured on one device never replays on another: the
+    platform and device kind are part of every plan key."""
+    import jax
+    from repro.compiler import cache as cache_mod
+    fp = cache_mod.env_fingerprint
+    assert fp("0.9.0", "cpu", "cpu") != fp("0.9.0", "tpu", "TPU v5 lite")
+    assert fp("0.9.0", "tpu", "TPU v5 lite") != fp("0.9.0", "tpu",
+                                                   "TPU v6 lite")
+    dev = jax.devices()[0]
+    assert cache_mod._env_fingerprint() == fp(jax.__version__, dev.platform,
+                                              dev.device_kind)
+    g, est = BUILDERS["vecadd"](64, vector_width=8)
+    store = CompileCache(tmp_path / "c.json")
+    key_here = compiler.measure_request_key(g, est)
+    store.put(key_here, {"factor": 4})
+    monkeypatch.setattr(cache_mod, "_env_fingerprint",
+                        lambda: fp(jax.__version__, "tpu", "TPU v5 lite"))
+    key_chip = compiler.measure_request_key(g, est)
+    assert key_chip != key_here and store.get(key_chip) is None

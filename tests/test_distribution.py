@@ -120,7 +120,7 @@ def test_train_step_lowers_on_host_mesh():
     step = steps_mod.make_train_step(cfg, optcfg, pump_factor=2)
     in_sh, out_sh, args = steps_mod.train_shardings(
         cfg, optcfg, mesh, shape, jnp.float32, pump_factor=2)
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step, in_shardings=in_sh,
                           out_shardings=out_sh).lower(*args)
         compiled = lowered.compile()
@@ -132,3 +132,41 @@ def test_mesh_factories():
     m = mesh_mod.make_host_mesh()
     assert set(m.axis_names) == {"data", "model"}
     assert mesh_mod.dp_degree(m) >= 1
+
+
+# ------------------------------------------------- installed mesh context --
+@pytest.mark.parametrize("opt_in,installed,constrained", [
+    (True, True, True), (False, True, False), (True, False, False)])
+def test_moe_ep_constraint_lowers_under_the_installed_mesh(
+        monkeypatch, opt_in, installed, constrained):
+    import contextlib
+    from repro.launch import mesh as mesh_mod
+    from repro.models.moe import _ep_constraint
+    monkeypatch.setenv("REPRO_MOE_EP_CONSTRAINT", "1" if opt_in else "0")
+    ctx = jax.set_mesh(mesh_mod.make_host_mesh()) if installed \
+        else contextlib.nullcontext()
+    with ctx:   # a fresh function: the env var is read at trace time
+        text = jax.jit(lambda a: _ep_constraint(a)).lower(
+            jnp.zeros((8, 4, 16))).as_text()
+    assert ("sharding_constraint" in text) == constrained
+
+
+def test_engine_traces_its_steps_under_its_mesh(monkeypatch):
+    """The engine's steps see the mesh through the abstract-mesh context,
+    which is what the MoE expert-parallel hint reads."""
+    from repro.models import moe
+    from repro.serve.engine import Engine, ServeConfig
+    from repro.models import model as model_mod
+    seen = []
+    real = moe._ep_constraint
+
+    def spy(arr):
+        seen.append(jax.sharding.get_abstract_mesh().axis_names)
+        return real(arr)
+
+    monkeypatch.setattr(moe, "_ep_constraint", spy)
+    cfg = load_arch("deepseek-v2-lite-16b", smoke=True)
+    eng = Engine(cfg, model_mod.init_params(cfg, jax.random.PRNGKey(0)),
+                 ServeConfig(batch=1, max_len=8))
+    eng.prefill(jnp.zeros((1, 4), jnp.int32))
+    assert seen and all(names == ("data", "model") for names in seen)

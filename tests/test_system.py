@@ -186,3 +186,31 @@ def test_trainer_checkpoint_resume_bitexact(tmp_path):
     w2 = jax.tree.leaves(out2["final_state"].params)[0]
     w3 = jax.tree.leaves(out3["final_state"].params)[0]
     np.testing.assert_allclose(np.asarray(w2), np.asarray(w3), atol=1e-6)
+
+
+def test_compile_cache_dir_respects_the_environment(monkeypatch):
+    """A set JAX_COMPILATION_CACHE_DIR is JAX's to read: the helper returns
+    it and sets no directory of its own."""
+    import jax
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jax-cache")
+    assert compile_cache.enable() == "/elsewhere/jax-cache"
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_default_is_one_fixed_path_in_the_checkout(
+        monkeypatch):
+    import jax
+    from pathlib import Path
+    from repro.launch import compile_cache
+    root = Path(__file__).resolve().parents[1]
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        first, second = compile_cache.enable(), compile_cache.enable()
+        assert first == second == str(root / ".cache" / "jax")
+        assert jax.config.jax_compilation_cache_dir == first
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert ".cache/" in (root / ".gitignore").read_text().split()

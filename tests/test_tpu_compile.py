@@ -1,0 +1,105 @@
+"""Compile the serving path's kernels for a described TPU v5e.
+
+No chip is attached: ``jax.experimental.topologies`` describes a v5e and the
+TPU compiler (installed with libtpu) compiles for it, refusing what the chip
+would refuse — a block shape that breaks the (8, 128) tiling, more VMEM
+than a kernel may use.  Interpret-mode tests cannot see either.
+
+Emission is steered inside the test (``plan_region`` + ``emit_pallas(...,
+interpret=False)``), because ``lower_pallas`` picks its tier from the
+attached devices, which here are CPUs.  The topology is described inside a
+module fixture, never at import time: only one process may hold libtpu, and
+every test worker imports this file.
+"""
+from __future__ import annotations
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.compiler import Pipeline
+from repro.compiler.pallas_backend import (emit_pallas, partition_regions,
+                                           plan_region, tpu_tiling_ok)
+from repro.core.autopump import BUILDERS
+from repro.core.ir import NodeKind
+
+FACTORS = (1, 2, 4, 8)
+# Qwen3-0.6B attention at its published width: 16 query heads over 8 KV
+# heads of 128, bf16, 128-row blocks
+QWEN3 = dict(h=16, hkv=8, d=128)
+FLASH = dict(bq=128, bkv=128, hkv=QWEN3["hkv"], causal=True,
+             dtype="bfloat16", itemsize=2)
+# case id -> (builder, args, kwargs); the serving path builds flash without
+# its row statistics (``stats=False``)
+CASES = {
+    "flash_attention": ("flash_attention",
+                        (1, QWEN3["h"], 2048, 2048, QWEN3["d"]), FLASH),
+    "flash_attention-serving": ("flash_attention",
+                                (1, QWEN3["h"], 2048, 2048, QWEN3["d"]),
+                                dict(FLASH, stats=False)),
+    "decode_attention": ("decode_attention", (4, QWEN3["h"], 2048, QWEN3["d"]),
+                         dict(bkv=128, hkv=QWEN3["hkv"], dtype="bfloat16",
+                              itemsize=2)),
+    "vecadd": ("vecadd", (1 << 20,), dict(vector_width=1024)),
+    "matmul": ("matmul", (1024, 1024, 1024), {}),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these compiles
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _compile_for_chip(kernel, args, kwargs, factor, one_chip):
+    g, est = BUILDERS[kernel](*args, **kwargs)
+    graph, _report = Pipeline.default(factor=factor, mode="T",
+                                      estimate=est).run(g)
+    fns = []
+    for region in partition_regions(graph):
+        notes = []
+        plan = plan_region(graph, region, notes.append)
+        assert plan is not None and plan.pallas_ok, notes
+        assert tpu_tiling_ok(graph, plan)
+        fns.append(emit_pallas(graph, plan, interpret=False))
+    inputs = {n.name: jax.ShapeDtypeStruct(n.shape, jnp.dtype(n.dtype),
+                                           sharding=one_chip)
+              for n in graph.nodes.values()
+              if n.kind == NodeKind.MEMORY and not graph.in_edges(n.name)}
+
+    def run(mems):
+        mems = dict(mems)
+        for fn in fns:
+            mems.update(fn(mems))
+        return mems
+
+    return jax.jit(run).lower(inputs).compile()
+
+
+@pytest.mark.parametrize("factor", FACTORS)
+@pytest.mark.parametrize("kernel", sorted(CASES))
+def test_kernel_compiles_for_v5e(one_chip, kernel, factor):
+    kernel, args, kwargs = CASES[kernel]
+    compiled = _compile_for_chip(kernel, args, kwargs, factor, one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
