@@ -243,7 +243,7 @@ def _engine_section(smoke: bool) -> dict:
 
     def raw_step():
         with jax.set_mesh(eng.mesh):
-            return eng.timer.run("decode", eng._decode, eng.params, cache,
+            return eng.timer.run("decode", eng._model_step, eng.params, cache,
                                  step_batch)
 
     # min-of-50 pairs, re-rolled up to 3 more rounds while the apparent

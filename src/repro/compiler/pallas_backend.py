@@ -55,6 +55,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import re
 from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
 
 import numpy as np
@@ -1023,9 +1024,12 @@ def lower_pallas(g: Graph, jit: bool = True, pallas_mode: str = "auto",
 
     regions = partition_regions(g)
     emitted: List[Tuple[Region, str, Callable]] = []
+    tag = ""            # _m<pump><mode> of the first region, for the name
     for region in regions:
         notes: List[str] = []
         plan = plan_region(g, region, notes.append)
+        if not emitted:
+            tag = f"_m{plan.pump if plan else region.pump}{region.mode}"
         if plan is not None and use_pallas and plan.pallas_ok \
                 and not interpret and not tpu_tiling_ok(g, plan):
             notes.append(f"region {region.name}: a block breaks the TPU "
@@ -1084,4 +1088,8 @@ def lower_pallas(g: Graph, jit: bool = True, pallas_mode: str = "auto",
     # produces garbage (NaNs) or dies at execution time — a no-op (the
     # original run_fn) unless fault rules are installed at lowering time
     run_fn = faults.wrap("emission.exec", run_fn, graph=g.name)
+    # the jitted wrapper's name becomes the HLO name of the kernel's custom
+    # call (``pallas_call(name=...)`` only sets an attribute), so a profiler
+    # trace tells the kernels apart: ``decode_attention_m1T.5``
+    run_fn.__name__ = run_fn.__qualname__ = re.sub(r"\W", "_", g.name + tag)
     return jax.jit(run_fn) if jit else run_fn

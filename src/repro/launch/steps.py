@@ -59,8 +59,15 @@ class StepTimer:
         self._warm: Dict[str, obs.Histogram] = {}
 
     def run(self, phase: str, fn, *args):
+        """Call ``fn(*args)`` and wait for its result.  The call and the
+        wait are spans of their own (``engine.dispatch``,
+        ``engine.wait``): device idle inside the first is host launch
+        cost."""
         t0 = time.perf_counter()
-        out = jax.block_until_ready(fn(*args))
+        with obs.span("engine.dispatch", cat="engine", phase=phase):
+            out = fn(*args)
+        with obs.span("engine.wait", cat="engine", phase=phase):
+            out = jax.block_until_ready(out)
         dt = time.perf_counter() - t0
         if phase not in self.compile_s:
             self.compile_s[phase] = dt

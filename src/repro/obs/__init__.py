@@ -17,7 +17,16 @@ Every layer of the reproduction reports through this package:
   fallbacks (``registry.*``), and publishes its stats as a snapshot view;
 * the **serve engine** wraps warmup/prefill/per-token decode in spans and
   records TTFT + per-token latency histograms, so one ``generate()`` call
-  under ``--trace`` yields a complete nested timeline.
+  under ``--trace`` yields a complete nested timeline;
+* the **scheduler** spans each step, admission, decode, logits copy and
+  sampling (``sched.*``), and the engine's step timer splits each call
+  into ``engine.dispatch`` and ``engine.wait``;
+* **JAX compilations** are counted (``jax.compiles``) once
+  :func:`watch_compiles` is called, as the engine does.
+
+While tracing, once JAX is loaded, the spans also land in any
+``jax.profiler`` capture (:func:`profile` with a ``logdir`` takes one), on
+the device trace's clock.
 
 Quick use::
 
@@ -40,13 +49,14 @@ from typing import Any, Dict
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       default_metrics, format_phases, format_snapshot,
                       set_default_metrics)
-from .profile import profile
+from .profile import profile, watch_compiles
 from .trace import (Tracer, disable, enable, get_tracer, instant, set_tracer,
-                    span, tracing_enabled, write_trace)
+                    span, step_span, tracing_enabled, write_trace)
 
 __all__ = [
     "Tracer", "get_tracer", "set_tracer", "enable", "disable",
-    "tracing_enabled", "span", "instant", "write_trace",
+    "tracing_enabled", "span", "step_span", "instant", "write_trace",
+    "watch_compiles",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "default_metrics",
     "set_default_metrics", "format_snapshot", "format_phases",
     "count", "observe", "gauge", "snapshot", "register_view", "profile",

@@ -60,6 +60,16 @@ class ServeConfig:
     nan_guard: bool = False
 
 
+def _jit_step(name: str, cfg: ModelConfig):
+    """``(params, cache, batch) -> (logits, cache)`` for ``cfg``, jitted
+    under ``name``: the compiled module (``jit_<name>``) and its ops carry
+    that name in a profiler trace."""
+    def step(params, cache, batch):
+        return model_mod.decode_step(cfg, params, batch, cache)
+    step.__name__ = step.__qualname__ = name
+    return jax.jit(step)
+
+
 class Engine:
     def __init__(self, cfg: ModelConfig, params, scfg: ServeConfig,
                  mesh=None):
@@ -73,8 +83,8 @@ class Engine:
         self.cfg, self.params, self.scfg = cfg, params, scfg
         self.mesh = mesh or mesh_mod.make_host_mesh()
         cdt = jnp.dtype(scfg.cache_dtype)
-        self._decode = jax.jit(
-            lambda p, c, b: model_mod.decode_step(cfg, p, b, c))
+        # one compiled step serves prefill (s > 1) and decode (s == 1)
+        self._model_step = _jit_step("model_step", cfg)
         self._cache_factory = lambda batch=None: model_mod.init_cache(
             cfg, batch or scfg.batch, scfg.max_len, cdt)
         # the bottom rung of the degradation ladder: a fully compiler-free
@@ -112,6 +122,7 @@ class Engine:
         # snapshot (a view over StepTimer, not a copy; the most recently
         # constructed engine owns the slot)
         obs.register_view("serve.engine", self.stats)
+        obs.watch_compiles()
         # resolved once: the decode loop records per-token latency straight
         # into the histogram object, skipping the name lookup per step
         self._step_hist = obs.default_metrics().histogram(
@@ -211,17 +222,13 @@ class Engine:
         """The plain-jnp bottom-rung step fn (lazily traced/compiled)."""
         if self._fallback_fn is None:
             obs.count("engine.fallback_build")
-            cfg = self._direct_cfg
-            self._fallback_fn = jax.jit(
-                lambda p, c, b: model_mod.decode_step(cfg, p, b, c))
+            self._fallback_fn = _jit_step("fallback_step", self._direct_cfg)
         return self._fallback_fn
 
     def _cont(self):
         """Continuation-prefill step fn (lazily traced/compiled)."""
         if self._cont_fn is None:
-            cfg = self._cont_cfg
-            self._cont_fn = jax.jit(
-                lambda p, c, b: model_mod.decode_step(cfg, p, b, c))
+            self._cont_fn = _jit_step("continuation_step", self._cont_cfg)
         return self._cont_fn
 
     def _fallback_cont(self):
@@ -232,8 +239,8 @@ class Engine:
             cfg = dataclasses.replace(
                 self._direct_cfg, prefill_continuation=True,
                 fresh_prefill_kernel=False)
-            self._fallback_cont_fn = jax.jit(
-                lambda p, c, b: model_mod.decode_step(cfg, p, b, c))
+            self._fallback_cont_fn = _jit_step(
+                "fallback_continuation_step", cfg)
         return self._fallback_cont_fn
 
     def _nan_guarded(self) -> bool:
@@ -250,7 +257,7 @@ class Engine:
         cont = phase == "prefill_chunk"
         try:
             faults.check(f"engine.{phase}")
-            step_fn = self._cont() if cont else self._decode
+            step_fn = self._cont() if cont else self._model_step
             with jax.set_mesh(self.mesh):
                 logits, new_cache = self.timer.run(
                     phase, step_fn, self.params, cache, batch)
@@ -269,13 +276,14 @@ class Engine:
 
     def prefill(self, tokens: jax.Array, enc_out=None):
         """tokens: (B, S_prompt) — returns (cache, last_logits)."""
-        cache = self._cache_factory(int(tokens.shape[0]))
         batch = {"tokens": tokens}
         if enc_out is not None:
             batch["enc_out"] = enc_out
         with obs.span("serve.prefill", cat="serve",
                       batch=int(tokens.shape[0]),
                       prompt_len=int(tokens.shape[1])):
+            with obs.span("engine.init_cache", cat="engine"):
+                cache = self._cache_factory(int(tokens.shape[0]))
             logits, cache = self._run_step("prefill", cache, batch)
         return cache, logits[:, -1]
 
@@ -307,9 +315,11 @@ class Engine:
         """One instrumented decode step — the serving hot path.
 
         The tracer-off path is kept deliberately lean (one enabled check,
-        one perf_counter pair, one cached-histogram append); its overhead
-        vs the uninstrumented step is measured per run by
-        ``benchmarks/serve_report.py`` (``engine.obs_overhead``, bar <2%).
+        one perf_counter pair, one cached-histogram append, the step
+        timer's two null spans).  Serving qwen3-0.6b over 32 lanes on a TPU
+        v5e, the decode step read 88.4-90.1 ms with tracing off against
+        88.6-88.9 ms without the scheduler's and timer's spans, and
+        88.8-89.7 ms with tracing on under a profiler capture (``PERF.md``).
         """
         t0 = time.perf_counter()
         tr = obs.get_tracer()

@@ -56,6 +56,16 @@ loop runs mixed-phase iterations:
   PRNG key chains, so every request's tokens are bit-identical to running
   it alone through :meth:`Engine.generate`.
 
+Each step is an ``obs`` step span (``sched.step``) holding its admissions
+(``sched.admit``: ``serve.prefill`` then ``sched.insert_rows``, with the
+admitted rids) and its decode (``sched.decode``: ``serve.decode``, then
+``sched.logits_to_host``, then ``sched.sample``), and the ``sched.*``
+counters count decode steps, lanes, prefill and padding rows and the logits
+bytes copied to the host.  Every request is stamped on the wall clock when
+it arrives (leaves ``pending`` at its arrival step) and when each of its
+tokens' logits reach the host (``CompletedRequest.arrival_wall``,
+``token_wall``).
+
 Time is *virtual*: arrivals are measured in scheduler steps, so a seeded
 :func:`synthetic_workload` replays deterministically — the property the
 invariant harness in ``tests/test_scheduler.py`` is built on (no slot
@@ -114,7 +124,11 @@ class Request:
 
 @dataclasses.dataclass
 class CompletedRequest:
-    """Per-request result + latency accounting for one streamed request."""
+    """Per-request result + latency accounting for one streamed request.
+
+    Wall times are ``time.perf_counter()`` readings: ``arrival_wall`` at
+    the start of the step the request arrived in, ``token_wall[i]`` when
+    the logits token ``i`` was sampled from reached the host."""
     rid: int
     tokens: np.ndarray                      # (n_new,) generated tokens
     arrival: int
@@ -127,6 +141,8 @@ class CompletedRequest:
     logits: Optional[np.ndarray] = None     # (n_new, V) fp32 when collected
     preemptions: int = 0                    # times evicted + resumed
     ttft_steps: int = 0                     # arrival -> first token (virtual)
+    arrival_wall: float = 0.0
+    token_wall: Optional[np.ndarray] = None  # (n_new,) float64
 
 
 @dataclasses.dataclass(frozen=True)
@@ -284,8 +300,8 @@ class _Lane:
     emitted: List[int] = dataclasses.field(default_factory=list)
     logits: List[np.ndarray] = dataclasses.field(default_factory=list)
     admitted_step: int = 0
-    admit_wall: float = 0.0
-    first_tok_wall: float = 0.0
+    arrival_wall: float = 0.0
+    token_wall: List[float] = dataclasses.field(default_factory=list)
     first_tok_step: int = -1
     degraded: bool = False
     preemptions: int = 0
@@ -305,6 +321,7 @@ class _QueueItem:
     PRNG chain and latency accounting)."""
     req: Request
     resume: Optional[_Lane] = None
+    arrival_wall: float = 0.0               # when it left ``pending``
 
 
 class Scheduler:
@@ -402,7 +419,8 @@ class Scheduler:
         if it.resume is not None:
             return it.resume
         return _Lane(req=it.req,
-                     key=jax.random.PRNGKey(self.engine.scfg.seed))
+                     key=jax.random.PRNGKey(self.engine.scfg.seed),
+                     arrival_wall=it.arrival_wall)
 
     def _qkey(self, it: _QueueItem):
         r = it.req
@@ -467,43 +485,43 @@ class Scheduler:
             lane.degraded = True
         self.slots.free(slot)
         del self.active[slot]
-        now = time.perf_counter()
         r = lane.req
         n = len(lane.emitted)
-        tpot = ((now - lane.first_tok_wall) / (n - 1)) if n > 1 else 0.0
+        walls = np.asarray(lane.token_wall, np.float64)
+        tpot = ((walls[-1] - walls[0]) / (n - 1)) if n > 1 else 0.0
+        ttft = float(walls[0] - lane.arrival_wall)
         if lane.degraded:
             self.engine.degraded_requests += 1
             obs.count("serve.degraded_request")
-        ttft_steps = lane.first_tok_step - r.arrival
-        obs.observe("serve.request_ttft_s",
-                    lane.first_tok_wall - lane.admit_wall)
+        obs.observe("serve.request_ttft_s", ttft)
         obs.observe("serve.request_tpot_s", tpot)
-        obs.observe("sched.ttft_steps", float(ttft_steps))
-        obs.count("serve.stream_tokens", n)
         self.completed[r.rid] = CompletedRequest(
             rid=r.rid, tokens=np.asarray(lane.emitted, np.int32),
             arrival=r.arrival, admitted_step=lane.admitted_step,
             done_step=self.step,
             queue_wait_steps=lane.admitted_step - r.arrival,
-            ttft_s=lane.first_tok_wall - lane.admit_wall, tpot_s=tpot,
+            ttft_s=ttft, tpot_s=tpot,
             degraded=lane.degraded,
             logits=(np.stack(lane.logits).astype(np.float32)
                     if self.collect_logits else None),
-            preemptions=lane.preemptions, ttft_steps=ttft_steps)
+            preemptions=lane.preemptions,
+            ttft_steps=lane.first_tok_step - r.arrival,
+            arrival_wall=lane.arrival_wall, token_wall=walls)
 
     def _first_token(self, slot: int, lane: _Lane, last_row: np.ndarray,
                      now: float) -> None:
         """Prefill finished for this lane: sample the first token (fresh
         admission) or restore the parked decode input (resume — the
         prefill logits predict a token that was already emitted before
-        preemption, so they are discarded)."""
+        preemption, so they are discarded).  ``now`` is when the prefill
+        logits reached the host."""
         if lane.emitted:
             lane.cur = lane.emitted[-1]
             return
         tok0 = self._sample_row(last_row, lane.key)
         lane.emitted.append(tok0)
         lane.cur = tok0
-        lane.first_tok_wall = time.perf_counter()
+        lane.token_wall.append(now)
         lane.first_tok_step = self.step
         if self.collect_logits:
             lane.logits.append(last_row)
@@ -528,7 +546,6 @@ class Scheduler:
                 lane = self._lane_for(it)
                 if it.resume is None:
                     lane.admitted_step = self.step
-                    lane.admit_wall = time.perf_counter()
                 lane.prefilling = True
                 lane.prefill_toks = self._prefill_tokens(it)
                 lane.prefill_done = 0
@@ -550,21 +567,29 @@ class Scheduler:
             if pad_to > g:
                 toks = np.concatenate(
                     [toks, np.repeat(toks[-1:], pad_to - g, axis=0)])
-            eng._req_degraded = False
-            small, last = eng.prefill(jnp.asarray(toks))
-            degraded = eng._req_degraded
-            now = time.perf_counter()
-            slot_ids = [self.slots.alloc(it.req.rid) for it in grp]
-            self.cache = insert_rows(self.cache, small, slot_ids, g)
-            last_h = np.asarray(last[:g], np.float32)
-            for i, (it, slot) in enumerate(zip(grp, slot_ids)):
-                lane = self._lane_for(it)
-                if it.resume is None:
-                    lane.admitted_step = self.step
-                    lane.admit_wall = now
-                lane.degraded = lane.degraded or degraded
-                self.active[slot] = lane
-                self._first_token(slot, lane, last_h[i], now)
+            rids = [it.req.rid for it in grp]
+            # a profiler annotation's stats are comma-separated text
+            rid_text = " ".join(map(str, rids))
+            obs.count("sched.prefill_rows", pad_to)
+            obs.count("sched.prefill_pad_rows", pad_to - g)
+            with obs.span("sched.admit", cat="sched", rids=rid_text,
+                          plen=plen, rows=pad_to, pad_rows=pad_to - g):
+                eng._req_degraded = False
+                small, last = eng.prefill(jnp.asarray(toks))
+                degraded = eng._req_degraded
+                slot_ids = [self.slots.alloc(rid) for rid in rids]
+                with obs.span("sched.insert_rows", cat="sched",
+                              rids=rid_text):
+                    self.cache = insert_rows(self.cache, small, slot_ids, g)
+                last_h = np.asarray(last[:g], np.float32)
+                now = time.perf_counter()
+                for i, (it, slot) in enumerate(zip(grp, slot_ids)):
+                    lane = self._lane_for(it)
+                    if it.resume is None:
+                        lane.admitted_step = self.step
+                    lane.degraded = lane.degraded or degraded
+                    self.active[slot] = lane
+                    self._first_token(slot, lane, last_h[i], now)
 
     def _advance_chunks(self) -> None:
         """Advance chunk-prefilling lanes, oldest admission first, within
@@ -593,13 +618,15 @@ class Scheduler:
             lane.prefill_done += take
             obs.count("sched.prefill_chunk")
             if lane.prefill_done == total:
-                self.cache = insert_rows(self.cache, lane.side, [slot], 1)
+                with obs.span("sched.insert_rows", cat="sched",
+                              rids=str(lane.req.rid)):
+                    self.cache = insert_rows(self.cache, lane.side, [slot],
+                                             1)
                 lane.side = None
                 lane.prefilling = False
                 lane.prefill_toks = None
-                self._first_token(slot, lane,
-                                  np.asarray(last[0], np.float32),
-                                  time.perf_counter())
+                last_h = np.asarray(last[0], np.float32)
+                self._first_token(slot, lane, last_h, time.perf_counter())
 
     # --------------------------------------------------------- preemption --
     def _maybe_preempt(self) -> List[int]:
@@ -685,26 +712,36 @@ class Scheduler:
                      if not ln.prefilling}
         if not decodable:
             return
-        toks = np.zeros((self.max_slots, 1), np.int32)
-        for slot, lane in decodable.items():
-            toks[slot, 0] = lane.cur
-        eng._req_degraded = False
-        logits, self.cache = eng._decode_token(
-            self.cache, {"tokens": jnp.asarray(toks)})
-        degraded = eng._req_degraded
-        rows = np.asarray(logits[:, -1], np.float32)
-        for slot, lane in list(decodable.items()):
-            if degraded:
-                lane.degraded = True
-            lane.key, sub = jax.random.split(lane.key)
-            tok = self._sample_row(rows[slot], sub)
-            lane.emitted.append(tok)
-            if self.collect_logits:
-                lane.logits.append(rows[slot])
-            if len(lane.emitted) >= lane.req.n_new:
-                self._finish(slot, lane)
-            else:
-                lane.cur = tok
+        lanes = len(decodable)
+        obs.count("sched.decode_steps")
+        obs.count("sched.decode_lanes", lanes)
+        with obs.span("sched.decode", cat="sched", lanes=lanes):
+            toks = np.zeros((self.max_slots, 1), np.int32)
+            for slot, lane in decodable.items():
+                toks[slot, 0] = lane.cur
+            eng._req_degraded = False
+            logits, self.cache = eng._decode_token(
+                self.cache, {"tokens": jnp.asarray(toks)})
+            degraded = eng._req_degraded
+            with obs.span("sched.logits_to_host", cat="sched"):
+                last = logits[:, -1]
+                rows = np.asarray(last, np.float32)
+            now = time.perf_counter()
+            obs.count("sched.logits_host_bytes", last.nbytes)
+            with obs.span("sched.sample", cat="sched", lanes=lanes):
+                for slot, lane in list(decodable.items()):
+                    if degraded:
+                        lane.degraded = True
+                    lane.key, sub = jax.random.split(lane.key)
+                    tok = self._sample_row(rows[slot], sub)
+                    lane.emitted.append(tok)
+                    lane.token_wall.append(now)
+                    if self.collect_logits:
+                        lane.logits.append(rows[slot])
+                    if len(lane.emitted) >= lane.req.n_new:
+                        self._finish(slot, lane)
+                    else:
+                        lane.cur = tok
 
     def submit(self, requests: Sequence[Request]) -> None:
         max_len = self.engine.scfg.max_len
@@ -721,64 +758,66 @@ class Scheduler:
     def run_step(self) -> None:
         """One scheduler step: arrivals -> shed sweep -> preemption ->
         admission -> prefill chunks -> batched decode."""
-        while self.pending and self.pending[0].arrival <= self.step:
-            r = self.pending.pop(0)
-            if self.max_queue is not None \
-                    and len(self.queue) >= self.max_queue:
-                self._shed_request(_QueueItem(req=r), "queue_full")
-            else:
-                self._enqueue(_QueueItem(req=r))
-        if self.deadline_aware:
-            # shed sweep: a queued request whose deadline cannot be met
-            # even by admitting it right now will never be met — count it
-            # out instead of burning slot time on it.  Preempted requests
-            # were admitted and are exempt: they always complete.
-            keep: List[_QueueItem] = []
-            for it in self.queue:
-                ds = self._deadline_step(it.req)
-                if it.resume is None and ds is not None \
-                        and self._min_done_step(it) > ds:
-                    self._shed_request(it, "deadline_unmeetable")
+        with obs.step_span("sched.step", self.step, cat="sched"):
+            now = time.perf_counter()
+            while self.pending and self.pending[0].arrival <= self.step:
+                it = _QueueItem(req=self.pending.pop(0), arrival_wall=now)
+                if self.max_queue is not None \
+                        and len(self.queue) >= self.max_queue:
+                    self._shed_request(it, "queue_full")
                 else:
-                    keep.append(it)
-            self.queue = keep
-        preempted = self._maybe_preempt()
-        admitted: List[_QueueItem] = []
-        while self.queue and len(admitted) < self.slots.free_count:
-            # always the queue head: a request never overtakes a
-            # better-ranked one into a slot (pure FIFO at equal rank)
-            admitted.append(self.queue.pop(0))
-        if admitted:
-            self._admit(admitted)
-        if self.prefill_chunk_tokens is not None:
-            self._advance_chunks()
-        self._decode()
-        obs.gauge("sched.slot_occupancy", self.slots.occupancy)
-        obs.gauge("sched.queue_depth", len(self.queue))
-        # conservation: every submitted request is exactly one of
-        # not-yet-arrived / queued / in-flight / completed / shed
-        accounted = (len(self.pending) + len(self.queue) + len(self.active)
-                     + len(self.completed) + len(self.shed))
-        if accounted != self._total:
-            raise RuntimeError(
-                f"request conservation violated at step {self.step}: "
-                f"{accounted} accounted vs {self._total} submitted")
-        if self.step_hook is not None:
-            self.step_hook({
-                "step": self.step,
-                "occupancy": self.slots.occupancy,
-                "free": self.slots.free_count,
-                "queue": [it.req.rid for it in self.queue],
-                "pending": len(self.pending),
-                "active": {s: ln.req.rid for s, ln in self.active.items()},
-                "admitted": [it.req.rid for it in admitted],
-                "completed": len(self.completed),
-                "shed": len(self.shed),
-                "preempted": preempted,
-                "prefilling": sorted(s for s, ln in self.active.items()
-                                     if ln.prefilling),
-            })
-        self.step += 1
+                    self._enqueue(it)
+            if self.deadline_aware:
+                # shed sweep: a queued request whose deadline cannot be met
+                # even by admitting it right now will never be met — count it
+                # out instead of burning slot time on it.  Preempted requests
+                # were admitted and are exempt: they always complete.
+                keep: List[_QueueItem] = []
+                for it in self.queue:
+                    ds = self._deadline_step(it.req)
+                    if it.resume is None and ds is not None \
+                            and self._min_done_step(it) > ds:
+                        self._shed_request(it, "deadline_unmeetable")
+                    else:
+                        keep.append(it)
+                self.queue = keep
+            preempted = self._maybe_preempt()
+            admitted: List[_QueueItem] = []
+            while self.queue and len(admitted) < self.slots.free_count:
+                # always the queue head: a request never overtakes a
+                # better-ranked one into a slot (pure FIFO at equal rank)
+                admitted.append(self.queue.pop(0))
+            if admitted:
+                self._admit(admitted)
+            if self.prefill_chunk_tokens is not None:
+                self._advance_chunks()
+            self._decode()
+            obs.gauge("sched.slot_occupancy", self.slots.occupancy)
+            obs.gauge("sched.queue_depth", len(self.queue))
+            # conservation: every submitted request is exactly one of
+            # not-yet-arrived / queued / in-flight / completed / shed
+            accounted = (len(self.pending) + len(self.queue) + len(self.active)
+                         + len(self.completed) + len(self.shed))
+            if accounted != self._total:
+                raise RuntimeError(
+                    f"request conservation violated at step {self.step}: "
+                    f"{accounted} accounted vs {self._total} submitted")
+            if self.step_hook is not None:
+                self.step_hook({
+                    "step": self.step,
+                    "occupancy": self.slots.occupancy,
+                    "free": self.slots.free_count,
+                    "queue": [it.req.rid for it in self.queue],
+                    "pending": len(self.pending),
+                    "active": {s: ln.req.rid for s, ln in self.active.items()},
+                    "admitted": [it.req.rid for it in admitted],
+                    "completed": len(self.completed),
+                    "shed": len(self.shed),
+                    "preempted": preempted,
+                    "prefilling": sorted(s for s, ln in self.active.items()
+                                         if ln.prefilling),
+                })
+            self.step += 1
 
     def run(self, requests: Sequence[Request]) -> List[CompletedRequest]:
         self.submit(requests)

@@ -6,6 +6,7 @@ input, metrics snapshots are pure JSON and round-trip, the cache health
 counters fire on corruption/staleness, and StepTimer's percentile stats are
 views over the obs histogram (one percentile implementation, not two).
 """
+import contextlib
 import json
 import threading
 import time
@@ -269,6 +270,142 @@ def test_profile_without_logdir_is_a_plain_span(tracer):
     assert rec["args"]["profiled"] is False and rec["args"]["tag"] == "x"
 
 
+def _host_events(logdir):
+    """``(name, start_ns, end_ns, stats)`` of every host event in the
+    ``jax.profiler`` capture under ``logdir``, in start order."""
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(str(logdir / "**" / "*.xplane.pb"), recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.append((ev.name, ev.start_ns,
+                            ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    return sorted(out, key=lambda e: e[1])
+
+
+def test_sink_puts_spans_in_the_profiler_trace(tracer, tmp_path):
+    """While tracing, spans, step spans and instants are host events of a
+    ``jax.profiler`` capture on the CPU, nested as they ran; attributes
+    become the events' stats, and an instant's carry ``instant``."""
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.step_span("t.step", 7):
+            with obs.span("t.outer", rows=3):
+                with obs.span("t.inner"):
+                    time.sleep(0.001)
+                obs.instant("t.tick", n=2)
+    finally:
+        jax.profiler.stop_trace()
+    evs = {e[0]: e for e in _host_events(tmp_path)
+           if e[0].startswith("t.")}
+    assert set(evs) == {"t.step", "t.outer", "t.inner", "t.tick"}
+    step, outer, inner, tick = (evs[k] for k in
+                                ("t.step", "t.outer", "t.inner", "t.tick"))
+    assert step[1] <= outer[1] <= inner[1] < inner[2] <= outer[2] <= step[2]
+    # an instant is an annotation entered and left at once
+    assert inner[2] <= tick[1] <= tick[2] <= outer[2]
+    assert tick[2] - tick[1] < inner[2] - inner[1]
+    assert step[3]["step_num"] == 7 and outer[3]["rows"] == 3
+    assert tick[3] == {"n": 2, "instant": 1}
+    assert "instant" not in outer[3] and "instant" not in inner[3]
+    # the JSON records are kept as before, the marker left out
+    assert [r["name"] for r in tracer.spans()] == ["t.inner", "t.outer",
+                                                    "t.step"]
+    assert [r["args"] for r in tracer.records if r["type"] == "event"] == \
+        [{"n": 2}]
+
+
+@pytest.mark.parametrize("enabled, jax_loaded",
+                         [(False, True), (True, False)])
+def test_no_annotation_unless_tracing_with_the_sink(tracer, monkeypatch,
+                                                    enabled, jax_loaded):
+    """Tracing off returns the shared null handle and makes no
+    annotation; tracing on makes none until the process has loaded JAX,
+    and never loads it."""
+    import sys
+    from repro.obs import trace as trace_mod
+    made = []
+
+    def fake(*a, **k):
+        made.append(a)
+        return contextlib.nullcontext()
+    monkeypatch.setattr(trace_mod, "_PROFILER", (fake, fake))
+    if not jax_loaded:
+        monkeypatch.setattr(trace_mod, "_PROFILER", None)
+        monkeypatch.setitem(sys.modules, "jax", None)
+    tracer.enabled = enabled
+    handle = obs.span("x")
+    with handle, obs.step_span("y", 1):
+        obs.instant("z")
+    assert made == []
+    if not jax_loaded:
+        assert trace_mod._PROFILER is None
+    if not enabled:
+        assert handle is obs.span("other")
+        assert tracer.records == []
+    else:
+        assert [r["name"] for r in tracer.records] == ["z", "y", "x"]
+
+
+def test_obs_imports_without_jax(tmp_path):
+    """``repro.obs`` needs the standard library alone, and traces without
+    JAX."""
+    import subprocess
+    import sys
+    code = ("import sys; sys.modules['jax'] = None\n"
+            "from repro import obs\n"
+            "from repro.obs import trace\n"
+            "obs.enable()\n"
+            "with obs.span('a'):\n"
+            "    obs.count('c')\n"
+            "assert obs.snapshot()['counters']['c'] == 1\n"
+            "assert [r['name'] for r in obs.get_tracer().records] == "
+            "['c', 'a']\n"
+            "if trace._profiler() is None:\n"
+            "    print('no jax')\n")
+    src = str(__import__("pathlib").Path(__file__).parents[1] / "src")
+    run = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120,
+                         env={"PYTHONPATH": src, "PATH": ""})
+    assert run.returncode == 0, run.stderr
+    assert run.stdout.strip() == "no jax"
+
+
+def test_watch_compiles_counts_and_marks_compiles(tracer, metrics):
+    """``jax.compiles`` counts each compilation once the listener is on;
+    while tracing, each also drops a ``jax.compile`` instant with its
+    duration."""
+    import jax
+    import numpy as np
+    obs.watch_compiles()
+    obs.watch_compiles()            # once per process, however often called
+    jax.jit(lambda x: x * 3 + 1)(np.ones(17, np.float32))
+    n = metrics.counter("jax.compiles").value
+    assert n >= 1
+    marks = [r for r in tracer.records if r["name"] == "jax.compile"]
+    assert len(marks) == n and all(m["args"]["dur_s"] >= 0 for m in marks)
+
+
+def test_profile_with_logdir_captures_program_spans(tmp_path):
+    """``obs.profile(logdir=...)`` turns tracing on for its capture, and
+    back off after."""
+    tracer = obs.get_tracer()
+    assert not tracer.enabled
+    with obs.profile("t.window", logdir=str(tmp_path)) as sp:
+        assert sp is not None and tracer.enabled
+        with obs.span("t.work"):
+            pass
+    assert not tracer.enabled
+    names = [e[0] for e in _host_events(tmp_path)]
+    assert "t.window" in names and "t.work" in names
+    tracer.clear()
+
+
 # ------------------------------------------------- end-to-end serve trace ----
 def test_engine_generate_produces_nested_trace(tracer, metrics, tmp_path,
                                                monkeypatch):
@@ -306,6 +443,13 @@ def test_engine_generate_produces_nested_trace(tracer, metrics, tmp_path,
     assert all(a["ts"] + a["dur"] <= b["ts"]
                for a, b in zip(decodes, decodes[1:]))
     assert gen["args"]["ttft_s"] > 0
+    # the step timer splits each call into its launch and its wait
+    assert [r["parent"] for r in tracer.spans("engine.init_cache")] == \
+        ["serve.prefill"]
+    for name in ("engine.dispatch", "engine.wait"):
+        parents = [r["parent"] for r in tracer.spans(name)]
+        assert parents.count("serve.decode") == 3
+        assert parents.count("serve.prefill") == 1
 
     snap = obs.snapshot()
     assert snap["counters"]["serve.tokens"] == 6
@@ -313,6 +457,63 @@ def test_engine_generate_produces_nested_trace(tracer, metrics, tmp_path,
     assert snap["histograms"]["serve.decode_step_s"]["count"] == 3
     # the engine's stats are published as a snapshot view
     assert snap["views"]["serve.engine"]["phases"]["decode"]["steps"] >= 1
+
+
+def test_scheduler_spans_nest_and_name_their_requests(tracer, metrics):
+    """Each scheduler step is a step span holding its admissions (with the
+    admitted rids, rows and padding) and its decode (logits copy, then
+    sampling); the counters add up to the spans."""
+    import dataclasses
+    import jax
+    from repro.configs.base import load_arch
+    from repro.models import model as model_mod
+    from repro.serve import scheduler as sched
+    from repro.serve.engine import Engine, ServeConfig
+
+    cfg = dataclasses.replace(load_arch("qwen3-0.6b", smoke=True),
+                              attention_impl="xla_chunked",
+                              kernel_plan="direct")
+    params = model_mod.init_params(cfg, jax.random.PRNGKey(0))
+    eng = Engine(cfg, params, ServeConfig(batch=2, max_len=16, warmup=False))
+    reqs = sched.synthetic_workload(5, seed=3, prompt_lens=(2, 4),
+                                    new_tokens=(2, 3), arrival_rate=0.5,
+                                    vocab=cfg.vocab_size)
+    eng.serve_stream(reqs, max_slots=3)
+
+    steps = tracer.spans("sched.step")
+    assert [r["depth"] for r in steps] == [1] * len(steps)   # serve.stream
+    admits = tracer.spans("sched.admit")
+    # rids are space-separated text: a profiler stat is comma-separated
+    groups = [[int(x) for x in r["args"]["rids"].split()] for r in admits]
+    assert sorted(rid for g in groups for rid in g) == list(range(5))
+    for r, g in zip(admits, groups):
+        assert r["parent"] == "sched.step"
+        # a group pads to the engine batch (2) when smaller
+        assert r["args"]["rows"] == max(2, len(g))
+        assert r["args"]["pad_rows"] == r["args"]["rows"] - len(g)
+    inserts = tracer.spans("sched.insert_rows")
+    assert [r["args"]["rids"] for r in inserts] == \
+        [r["args"]["rids"] for r in admits]
+    assert {r["parent"] for r in inserts} == {"sched.admit"}
+    assert {r["parent"] for r in tracer.spans("serve.prefill")} == \
+        {"sched.admit"}
+    decodes = tracer.spans("sched.decode")
+    for name in ("serve.decode", "sched.logits_to_host", "sched.sample"):
+        assert [r["parent"] for r in tracer.spans(name)] == \
+            ["sched.decode"] * len(decodes)
+    c = metrics.snapshot(include_views=False)["counters"]
+    assert c["sched.decode_steps"] == len(decodes)
+    assert c["sched.decode_lanes"] == sum(r["args"]["lanes"]
+                                          for r in decodes)
+    assert c["sched.prefill_rows"] == sum(r["args"]["rows"] for r in admits)
+    assert c["sched.prefill_pad_rows"] == \
+        sum(r["args"]["pad_rows"] for r in admits)
+    assert c["sched.logits_host_bytes"] == \
+        len(decodes) * 3 * cfg.vocab_size * 4
+    for gone in ("serve.stream_tokens", "sched.ttft_steps"):
+        assert gone not in c
+        assert gone not in metrics.snapshot(include_views=False)[
+            "histograms"]
 
 
 def test_scheduler_metrics_on_two_rate_trace(metrics, tmp_path, monkeypatch):

@@ -425,6 +425,63 @@ def test_preempted_request_resumes_bit_exact(engine):
                                       err_msg=f"rid {r.rid}")
 
 
+@pytest.mark.parametrize("preempt", [False, True])
+def test_request_wall_records(engine, preempt):
+    """Each completed request carries one non-decreasing wall stamp per
+    token, and its TTFT runs from its arrival: stamped in the step it
+    arrived in, before its first token.  A preempted request keeps the
+    stamps of the tokens it emitted before its eviction."""
+    rng = np.random.default_rng(1)
+    reqs = [sched.Request(i, rng.integers(0, engine.cfg.vocab_size, 4),
+                          6 if i < 2 else 3, arrival=i // 2,
+                          priority=5 if preempt and i == 2 else 0)
+            for i in range(4)]
+    s = sched.Scheduler(
+        engine, max_slots=2,
+        preempt_policy="lowest_priority" if preempt else None)
+    s.submit(reqs)
+    starts = []                     # wall clock as each step begins
+    while s.pending or s.queue or s.active:
+        starts.append(time.perf_counter())
+        s.run_step()
+    starts.append(time.perf_counter())
+    assert (s.preempt_count > 0) == preempt
+    for r in reqs:
+        c = s.completed[r.rid]
+        assert c.token_wall.dtype == np.float64
+        assert c.token_wall.shape == (r.n_new,) == c.tokens.shape
+        assert np.all(np.diff(c.token_wall) >= 0)
+        assert starts[r.arrival] <= c.arrival_wall < starts[r.arrival + 1]
+        assert c.arrival_wall <= c.token_wall[0]
+        assert c.ttft_s == c.token_wall[0] - c.arrival_wall
+
+
+def test_ttft_runs_from_arrival_not_submission(engine):
+    """A request submitted long before its virtual arrival step counts
+    none of the steps before it in its TTFT: it is no longer than its
+    first token minus the start of the step it arrived in."""
+    rng = np.random.default_rng(2)
+    late = 6
+    reqs = [sched.Request(0, rng.integers(0, engine.cfg.vocab_size, 4), 8,
+                          arrival=0),
+            sched.Request(1, rng.integers(0, engine.cfg.vocab_size, 4), 2,
+                          arrival=late)]
+    s = sched.Scheduler(engine, max_slots=2)
+    submitted = time.perf_counter()
+    s.submit(reqs)
+    starts = []
+    while s.pending or s.queue or s.active:
+        starts.append(time.perf_counter())
+        s.run_step()
+    c = s.completed[1]
+    assert c.ttft_steps == 0 and c.admitted_step == late
+    assert c.ttft_s <= c.token_wall[0] - starts[late]
+    assert c.ttft_s < c.token_wall[0] - submitted
+    # the early request's TTFT does not run from the late one's arrival
+    assert 0 < s.completed[0].ttft_s <= \
+        s.completed[0].token_wall[0] - starts[0]
+
+
 def test_admission_control_sheds_with_named_reasons(engine):
     """queue_full fires on a bounded queue under burst arrivals;
     deadline_unmeetable fires on a deadline no admission could meet.
