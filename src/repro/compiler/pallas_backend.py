@@ -1053,6 +1053,12 @@ def lower_pallas(g: Graph, jit: bool = True, pallas_mode: str = "auto",
         # regression to the slow tier must be attributable from telemetry
         # alone, not only from a PipelineReport someone kept around
         obs.count(f"emission.tier.{tier}", graph=g.name, region=region.name)
+        if plan is not None:
+            # a kernel's cost follows its grid steps as much as its bytes:
+            # device time / (calls × grid_points) is the time per step
+            obs.count("emission.grid_points",
+                      int(np.prod([e for _s, e in plan.grid])),
+                      graph=g.name, region=region.name)
         if notes:
             obs.count("emission.degraded", graph=g.name,
                       region=region.name, tier=tier, why="; ".join(notes))

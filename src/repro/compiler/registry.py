@@ -367,8 +367,12 @@ class PlanRegistry:
         out = []
         for (kernel, args, kwargs, pump, backend), kern in self._plans.items():
             tuned = kern.report.autotune or {}
+            # the grid of the region that names the kernel (its first)
+            regions = list((kern.report.emission or {}).values())
             out.append({
                 "kernel": kernel, "args": list(args),
+                "bkv": dict(kwargs).get("bkv"),
+                "grid": regions[0]["grid"] if regions else None,
                 "factor": kern.spec.factor, "mode": kern.spec.mode,
                 "pump": pump, "backend": backend,
                 "measured": tuned.get("policy") == "measure",
@@ -416,18 +420,23 @@ class PlanRegistry:
         return args, kwargs, (bb, lb)
 
     def decode_request(self, *, b: int, h: int, hkv: int, t: int, d: int,
-                       dtype: str, bkv: int = 128):
+                       dtype: str, bkv: Optional[int] = None):
         """S=1 decode attention bucket: ``t`` is the attended cache prefix
         (pos + 1 when the position is concrete, the full preallocated cache
         length under a jit trace) and buckets on the same pow2 ladder as
         prefill sequence dims — a growing decode context touches O(log T)
-        plans, keyed separately from prefill by the kernel name."""
+        plans, keyed separately from prefill by the kernel name.  The KV
+        tile is the decode graph's own choice for the bucket's shapes
+        (:func:`~repro.core.autopump.decode_kv_tile`) unless ``bkv`` is
+        given."""
+        from repro.core.autopump import decode_kv_tile
         bb = self.policy.bucket_batch(b)
         tb = self.policy.bucket_seq(t)
-        bkv_e = _fit_block(bkv, tb)
+        itemsize = jnp.dtype(dtype).itemsize
+        bkv_e = decode_kv_tile(tb, d, h // hkv, itemsize) if bkv is None \
+            else _fit_block(bkv, tb)
         args = (bb, h, tb, d)
-        kwargs = dict(bkv=bkv_e, hkv=hkv, dtype=dtype,
-                      itemsize=jnp.dtype(dtype).itemsize)
+        kwargs = dict(bkv=bkv_e, hkv=hkv, dtype=dtype, itemsize=itemsize)
         return args, kwargs, (bb, tb)
 
     def ssd_decode_request(self, *, b: int, h: int, p: int, n: int,
@@ -552,11 +561,13 @@ class PlanRegistry:
             return y            # exact bucket: skip the slice dispatch
         return y[:b, :l]
 
-    def decode_attention(self, q, k_cache, v_cache, pos, *, bkv: int = 128):
-        """Kernelized S=1 decode: one query row against the preallocated
-        KV cache.  q: (B, H, D); caches: (B, Hkv, T, D); ``pos`` is the
-        current write position (scalar or (B,) int32 — valid cache slots
-        are 0..pos, enforced by the kernel's symbolic position mask).
+    def decode_attention(self, q, k_cache, v_cache, pos, *,
+                         bkv: Optional[int] = None):
+        """Kernelized S=1 decode: one query row per head against the
+        preallocated KV cache.  q: (B, H, D); caches: (B, Hkv, T, D);
+        ``pos`` is the current write position (scalar or (B,) int32 — valid
+        cache slots are 0..pos, enforced by the kernel's symbolic position
+        mask).
 
         With a *concrete* ``pos`` (eager serving / benchmarks) the cache is
         sliced to the pos bucket before the call, so a decode step costs
@@ -586,12 +597,15 @@ class PlanRegistry:
                           f"the plain jnp path ({e})", stacklevel=2)
             return _decode_reference(q, k_cache, v_cache, pos)
         t_keep = min(tb, t)     # bucket ≥ pos+1, so no valid slot is cut
-        qp = _pad_axes(q, {0: bb})
+        # query head i reads KV head i // group: the kernel's (B, Hkv, group,
+        # D) query and output are free reshapes of (B, H, D)
+        qp = _pad_axes(q.reshape(b, hkv, h // hkv, d), {0: bb})
         kp = _pad_axes(k_cache[:, :, :t_keep], {0: bb, 2: tb})
         vp = _pad_axes(v_cache[:, :, :t_keep], {0: bb, 2: tb})
         pp = _pad_axes(_pos_vec(pos, b), {0: bb})
         try:
             out = kern({"q": qp, "k": kp, "v": vp, "pos": pp})["o"]
+            out = out.reshape(bb, h, d)
         except Exception as e:  # noqa: BLE001 — exec failure: degrade a rung
             self.stats.fallback("decode_attention", why=f"exec: {e}")
             warnings.warn(f"plan registry: decode_attention kernel execution "

@@ -23,7 +23,7 @@ import numpy as np
 
 from .ir import CarrySpec, Graph, PumpSpec
 from .multipump import PumpReport
-from .pump_plan import KernelEstimate, VMEM_BYTES
+from .pump_plan import SUBLANE, KernelEstimate, VMEM_BYTES
 from .symbolic import AccessPattern, Affine, Domain
 
 
@@ -483,77 +483,96 @@ def _ssd_graph(b: int, l: int, h: int, p: int, n: int, chunk: int = 64,
     return g, est
 
 
-def _decode_attention_graph(b: int, h: int, t: int, d: int, bkv: int = 128,
-                            itemsize: int = 4, hkv: Optional[int] = None,
+# share of the kernel VMEM budget one decode grid step's KV tile may take:
+# double-buffered K and V blocks, their f32 working copies and the scores
+DECODE_TILE_SHARE = 16
+
+
+def decode_kv_tile(t: int, d: int, group: int = 1, itemsize: int = 4) -> int:
+    """KV tile of the decode graph: the largest power of two dividing ``t``
+    whose working set fits ``VMEM_BYTES / DECODE_TILE_SHARE`` — per key, two
+    buffers of K and V at ``itemsize``, their f32 copies and ``group`` f32
+    scores and probabilities.  Where ``t / 2`` is still a whole number of
+    sublanes the tile is at most ``t / 2``, so the carry axis keeps two
+    steps for mode T to pump."""
+    per_key = 2 * 2 * d * itemsize + 2 * d * 4 + 2 * group * 4
+    cap = t // 2 if t % (2 * SUBLANE) == 0 else t
+    bkv = 1
+    while cap % (2 * bkv) == 0 \
+            and 2 * bkv * per_key <= VMEM_BYTES // DECODE_TILE_SHARE:
+        bkv *= 2
+    return bkv
+
+
+def _decode_attention_graph(b: int, h: int, t: int, d: int,
+                            bkv: Optional[int] = None, itemsize: int = 4,
+                            hkv: Optional[int] = None,
                             scale: Optional[float] = None,
                             dtype: str = "float32",
                             vector_width: Optional[int] = None):
     """Incremental (S=1) attention against a preallocated KV cache.
 
-    One query row per (batch, head) runs the online-softmax recurrence over
-    KV tiles — the same sequential-carry axis (``ji``) as prefill flash
-    attention, but with the causal mask replaced by a *position-offset*
+    One grid point per (batch row, KV head, KV tile): the ``group = h / hkv``
+    query heads that share a KV head ride in one block, so the query memory
+    is ``(b, hkv, group, d)`` (query head ``i`` reads KV head ``i // group``,
+    a free reshape of ``(b, h, d)``) and each K/V tile is read once per KV
+    head.  The step runs the online-softmax recurrence on ``(group, bkv)``
+    scores over KV tiles — the same sequential-carry axis (``ji``) as prefill
+    flash attention, with the causal mask replaced by a *position-offset*
     validity mask: an int32 ``pos`` input (one per batch row) marks the last
     written cache slot, and each step masks keys symbolically via
     ``k_pos <= pos`` (k_pos derived from the carry step index — no
     materialized boolean, so a bucketed cache length costs only the mask
-    compare).  GQA head folding is the same group-indexed table as prefill.
+    compare).  ``bkv`` defaults to :func:`decode_kv_tile` of the shapes.
     """
     hkv = hkv or h
+    if h % hkv:
+        raise ValueError(f"{h} query heads do not group over {hkv} KV heads")
+    group = h // hkv
+    if bkv is None:
+        bkv = decode_kv_tile(t, d, group, itemsize)
+    bkv = min(bkv, t)
+    if t % bkv:
+        raise ValueError(f"cache length {t} is not a multiple of bkv={bkv}")
     g = Graph("decode_attention")
-    g.memory("q", (b, h, d), dtype=dtype)
+    g.memory("q", (b, hkv, group, d), dtype=dtype)
     g.memory("k", (b, hkv, t, d), dtype=dtype)
     g.memory("v", (b, hkv, t, d), dtype=dtype)
     g.memory("pos", (b,), dtype="int32")
-    g.memory("o", (b, h, d), dtype=dtype)
-    bkv = min(bkv, t)
+    g.memory("o", (b, hkv, group, d), dtype=dtype)
     if scale is None:
         scale = d ** -0.5
     if vector_width is None:
         vector_width = d // 128 or 1
-    est = KernelEstimate(block_bytes_in=2 * bkv * d * itemsize,
+    est = KernelEstimate(block_bytes_in=(2 * bkv + group) * d * itemsize,
                          block_bytes_out=0.0,
-                         flops_per_block=4.0 * bkv * d)
+                         flops_per_block=4.0 * group * bkv * d)
 
     nj = t // bkv
-    dom = Domain.of(("bi", 0, b), ("hi", 0, h), ("ji", 0, max(nj, 1)))
-    if t % bkv or h % hkv:
-        # corner-sampled transaction schedule: planning/legality only
-        acc_kv = AccessPattern(dom, (Affine.of("bi"), Affine.of("hi"),
-                                     Affine.of("ji", bkv),
-                                     Affine.constant(0)), width=1)
-        acc_o = AccessPattern(dom, (Affine.of("bi"), Affine.of("hi"),
-                                    Affine.constant(0)), width=1)
-        g.compute("decode_softmax", dom, vector_width=vector_width)
-        g.connect("q", "decode_softmax", acc_o)
-        g.connect("k", "decode_softmax", acc_kv)
-        g.connect("v", "decode_softmax", acc_kv)
-        g.connect("decode_softmax", "o", acc_o)
-        return g, est
-
-    group = h // hkv
-    head = Affine.of("hi") if group == 1 else \
-        Affine.table("hi", [i // group for i in range(h)])
-    acc_q = AccessPattern(dom, (Affine.of("bi"), Affine.of("hi"),
-                                Affine.constant(0)), width=d)
-    dom_kv = Domain.of(("bi", 0, b), ("hi", 0, h), ("ji", 0, nj),
+    dom = Domain.of(("bi", 0, b), ("kvh", 0, hkv), ("ji", 0, nj))
+    # the query and output blocks span the whole (group, d) head group
+    acc_q = AccessPattern(dom, (Affine.of("bi"), Affine.of("kvh"),
+                                Affine.constant(0), Affine.constant(0)),
+                          width=group * d)
+    dom_kv = Domain.of(("bi", 0, b), ("kvh", 0, hkv), ("ji", 0, nj),
                        ("r", 0, bkv))
-    acc_kv = AccessPattern(dom_kv, (Affine.of("bi"), head,
+    acc_kv = AccessPattern(dom_kv, (Affine.of("bi"), Affine.of("kvh"),
                                     _blk("ji", bkv, nj) + Affine.of("r"),
                                     Affine.constant(0)), width=d)
     acc_pos = AccessPattern(dom, (Affine.of("bi"),), width=1)
-    dom_o = Domain.of(("bi", 0, b), ("hi", 0, h))
-    acc_o = AccessPattern(dom_o, (Affine.of("bi"), Affine.of("hi"),
-                                  Affine.constant(0)), width=d)
+    dom_o = Domain.of(("bi", 0, b), ("kvh", 0, hkv))
+    acc_o = AccessPattern(dom_o, (Affine.of("bi"), Affine.of("kvh"),
+                                  Affine.constant(0), Affine.constant(0)),
+                          width=group * d)
 
     def step_fn(carry, q_blk, k_blk, v_blk, pos_blk, idx=None):
         xp = _xp(q_blk)
         f32 = xp.float32
         m_run, l_run, acc = carry
-        q2 = q_blk.reshape(1, q_blk.shape[-1]).astype(f32)
+        q2 = q_blk.reshape(q_blk.shape[-2], q_blk.shape[-1]).astype(f32)
         k2 = k_blk.reshape(k_blk.shape[-2], k_blk.shape[-1]).astype(f32)
         v2 = v_blk.reshape(v_blk.shape[-2], v_blk.shape[-1]).astype(f32)
-        sc = (q2 * f32(scale)) @ k2.T                      # (1, bkv)
+        sc = (q2 * f32(scale)) @ k2.T                      # (group, bkv)
         k_pos = idx["step"] * bkv + _iota(xp, sc.shape, 1)
         sc = xp.where(k_pos <= pos_blk.reshape(1, 1), sc, f32(NEG_INF))
         m_new = xp.maximum(m_run, sc.max(axis=-1, keepdims=True))
@@ -567,20 +586,20 @@ def _decode_attention_graph(b: int, h: int, t: int, d: int, bkv: int = 128,
         xp = _xp(carry[0])
         m_run, l_run, acc = carry
         l_safe = xp.where(l_run == 0.0, xp.float32(1.0), l_run)
-        return {"out0": (acc / l_safe)[None]}              # (1, 1, d')
+        return {"out0": (acc / l_safe)[None, None]}        # (1, 1, group, d')
 
     g.compute(
         "decode_softmax", dom, vector_width=vector_width,
         carry=CarrySpec(
             axis="ji",
-            state=(((1, 1), "float32", NEG_INF), ((1, 1), "float32"),
-                   ((1, d), "float32")),
+            state=(((group, 1), "float32", NEG_INF), ((group, 1), "float32"),
+                   ((group, d), "float32")),
             step_fn=step_fn, final_fn=final_fn, pass_idx=True),
-        # the query row and the scores span the full head dim (it is the
+        # the query rows and the scores span the full head dim (it is the
         # softmax contraction), so mode R narrows only the value path:
         # v / accumulator / output walk d in M sub-tiles
         axes=dict(ins=({}, {}, {3: "d"}, {}),
-                  outs=({2: "d"},),
+                  outs=({3: "d"},),
                   carry=({}, {}, {1: "d"}),
                   narrow="d"))
     g.connect("q", "decode_softmax", acc_q)

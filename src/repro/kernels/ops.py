@@ -346,7 +346,7 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 16,
 
 
 # ------------------------------------------------------- decode attention --
-def decode_attention(q, k_cache, v_cache, pos, *, bkv: int = 128,
+def decode_attention(q, k_cache, v_cache, pos, *, bkv: int | None = None,
                      pump: PumpSpec | int | str = 1, impl: str = "compiler"):
     """Single-position (S=1) attention against a preallocated KV cache.
 
@@ -356,21 +356,21 @@ def decode_attention(q, k_cache, v_cache, pos, *, bkv: int = 128,
     index compare derived from the carry step, never a materialized (B, T)
     boolean).  Compiler-only: the decode builder has no hand-wired
     counterpart; serving routes here through the plan registry
-    (``PlanRegistry.decode_attention``), which adds pos-bucketing."""
+    (``PlanRegistry.decode_attention``), which adds pos-bucketing.  ``bkv``
+    defaults to the decode graph's own tile for these shapes."""
     if impl != "compiler":
         raise ValueError("decode_attention is compiler-only")
     b, h, d = q.shape
     hkv, t = k_cache.shape[1], k_cache.shape[2]
-    bkv_e = min(bkv, t)
-    if t % bkv_e:
-        raise ValueError(f"T={t} %% bkv={bkv_e} != 0")
     kern = _compile_kernel(
         "decode_attention", (b, h, t, d),
-        dict(bkv=bkv_e, hkv=hkv, dtype=str(q.dtype),
+        dict(bkv=bkv, hkv=hkv, dtype=str(q.dtype),
              itemsize=q.dtype.itemsize), pump)
     posv = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(pos, jnp.int32)),
                             (b,))
-    return kern({"q": q, "k": k_cache, "v": v_cache, "pos": posv})["o"]
+    out = kern({"q": q.reshape(b, hkv, h // hkv, d), "k": k_cache,
+                "v": v_cache, "pos": posv})["o"]
+    return out.reshape(b, h, d)
 
 
 # -------------------------------------------------------------- ssd decode --

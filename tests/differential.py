@@ -78,18 +78,42 @@ def _decode_transform(positions):
 
 
 def _decode_gold(inputs):
+    # the kernel's query and output hold the group of query heads that
+    # share a KV head: (b, hkv, group, d)
     q, k, v, pos = inputs["q"], inputs["k"], inputs["v"], inputs["pos"]
-    b, h, d = q.shape
-    group = h // k.shape[1]
-    kk = np.repeat(k, group, axis=1)
-    vv = np.repeat(v, group, axis=1)
-    sc = np.einsum("bhd,bhtd->bht", q * np.float32(d ** -0.5), kk)
-    mask = np.arange(k.shape[2])[None, None, :] <= pos[:, None, None]
+    sc = np.einsum("bkgd,bktd->bkgt", q * np.float32(q.shape[-1] ** -0.5), k)
+    mask = np.arange(k.shape[2])[None, None, None, :] \
+        <= pos[:, None, None, None]
     sc = np.where(mask, sc, -1e30)
     m = sc.max(-1, keepdims=True)
     p = np.exp(sc - m)
-    o = np.einsum("bht,bhtd->bhd", p / p.sum(-1, keepdims=True), vv)
+    o = np.einsum("bkgt,bktd->bkgd", p / p.sum(-1, keepdims=True), v)
     return {"o": o.astype(np.float32)}
+
+
+def decode_case(b: int, h: int, hkv: int, t: int, d: int,
+                bkv: Optional[int], positions: Sequence[int],
+                seed: int = 0) -> Case:
+    """A decode-attention case: ``h`` query heads over ``hkv`` KV heads of
+    ``d``, a cache of ``t`` slots in tiles of ``bkv`` (None: the graph's own
+    tile), one write position per batch row."""
+    return Case(
+        "decode_attention", (b, h, t, d),
+        dict(bkv=bkv, hkv=hkv, vector_width=4),
+        {"q": (b, hkv, h // hkv, d), "k": (b, hkv, t, d),
+         "v": (b, hkv, t, d), "pos": (b,)},
+        ("o",), exact=False, transform=_decode_transform(positions),
+        gold=_decode_gold, seed=seed)
+
+
+# decode attention across GQA groups and KV tilings (one tile, the graph's
+# own tile, several tiles), each case with a mid-cache and a cache-full row
+DECODE_CASES = {
+    f"g{group}-t{t}-bkv{bkv or 'auto'}": decode_case(
+        2, 2 * group, 2, t, 8, bkv, [t // 2 + 1, t - 1], seed=group)
+    for group in (1, 2, 4)
+    for t, bkv in ((16, 16), (32, None), (32, 8))
+}
 
 
 def _ssd_decode_gold(inputs):
@@ -168,14 +192,8 @@ def cases(shape_index: int = 0) -> Dict[str, Case]:
                      vector_width=8),
                 {"x": (40, 16), "w": (2, 16, 8)}, ("o",),
                 gold=_grouped_gold_ragged((16, 24))),
-            "decode_attention": Case(
-                "decode_attention", (2, 4, 32, 8),
-                dict(bkv=8, hkv=2, vector_width=4),       # GQA fold
-                {"q": (2, 4, 8), "k": (2, 2, 32, 8), "v": (2, 2, 32, 8),
-                 "pos": (2,)},
-                ("o",), exact=False,
-                transform=_decode_transform([17, 31]),    # mid / cache-full
-                gold=_decode_gold),
+            # GQA fold; mid-cache and cache-full rows
+            "decode_attention": decode_case(2, 4, 2, 32, 8, 8, [17, 31]),
             "ssd_scan_final": Case(
                 "ssd_scan", (1, 32, 2, 4, 4),
                 dict(chunk=8, vector_width=8, final_state=True),
@@ -223,14 +241,8 @@ def cases(shape_index: int = 0) -> Dict[str, Case]:
                  vector_width=8),
             {"x": (40, 8), "w": (3, 8, 8)}, ("o",),
             gold=_grouped_gold_ragged((8, 24, 8)), seed=1),
-        "decode_attention": Case(
-            "decode_attention", (1, 4, 16, 4),
-            dict(bkv=4, hkv=2, vector_width=4),
-            {"q": (1, 4, 4), "k": (1, 2, 16, 4), "v": (1, 2, 16, 4),
-             "pos": (1,)},
-            ("o",), exact=False,
-            transform=_decode_transform([0]),             # fresh cache
-            gold=_decode_gold, seed=1),
+        "decode_attention": decode_case(1, 4, 2, 16, 4, 4, [0],  # fresh
+                                        seed=1),
         "ssd_scan_final": Case(
             "ssd_scan", (2, 16, 4, 8, 2),
             dict(chunk=4, n_groups=2, vector_width=8, final_state=True),
@@ -248,9 +260,10 @@ def cases(shape_index: int = 0) -> Dict[str, Case]:
 
 
 def run_case(case: Case, factor: int, mode: str, backend: str,
-             cache=False, pallas_mode: str = "auto") -> None:
+             cache=False, pallas_mode: str = "auto"):
     """Compile one case and assert it against the reference executor (and
-    the independent numpy gold, when the case carries one)."""
+    the independent numpy gold, when the case carries one); returns the
+    compiled kernel."""
     g, _est = BUILDERS[case.kernel](*case.args, **case.kwargs)
     kern = compiler.compile(g, factor=factor, mode=mode, backend=backend,
                             pallas_mode=pallas_mode, cache=cache,
@@ -276,6 +289,7 @@ def run_case(case: Case, factor: int, mode: str, backend: str,
                 np.asarray(out[name]), value, rtol=1e-5, atol=1e-5,
                 err_msg=f"{case.kernel}:{name} vs semantics "
                         f"(M={factor} {mode} {backend})")
+    return kern
 
 
 def sweep(kernels: Optional[Sequence[str]] = None,

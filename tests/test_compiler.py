@@ -826,7 +826,7 @@ def test_autopump_routes_through_pipeline(tmp_path):
                                     "ssd_decode", "flash_attention"])
 def test_tile_views_keep_interpret_emission_exact(kernel):
     """Blocks that break the TPU tiling rule run through unit-axis views of
-    their memories (decode's (1, 1, d) query rows and (1,) positions,
+    their memories (decode's (1,) positions, ssd_decode's per-head rows,
     vecadd's 8-element blocks); the views must not change a result."""
     run_case(_DIFF0[kernel], 2, "T", "pallas", pallas_mode="interpret")
 
@@ -837,7 +837,7 @@ def test_tile_view_rules():
     assert tile_view((1, 16, 2048, 128), (1, 1, 128, 128), 2).kind == "same"
     # flash row statistics keep a trailing unit axis: (bq, 1) is legal
     assert tile_view((1, 16, 2048, 1), (1, 1, 128, 1), 4).kind == "same"
-    # one decode query row per (batch, head): a unit axis before the last
+    # one (1, 1, d) row per (batch, head): a unit axis before the last
     v = tile_view((4, 16, 128), (1, 1, 128), 2)
     assert (v.kind, v.shape, v.block) == \
         ("unit", (4, 16, 1, 128), (1, 1, 1, 128))

@@ -14,6 +14,9 @@ import pytest
 from repro.compiler.registry import (PlanRegistry, default_registry,
                                      set_default_registry)
 from repro.configs.base import load_arch
+from repro.core.autopump import decode_kv_tile
+
+from differential import DECODE_CASES, FACTORS, MODES, run_case
 
 
 @pytest.fixture(autouse=True)
@@ -39,6 +42,57 @@ def _gqa_setup(max_len=32, b=2):
     cache = {"k": _ints(kshape, 1), "v": _ints(kshape, 2)}
     x1 = _ints((b, 1, cfg.d_model), 3)
     return cfg, p, cache, x1
+
+
+# --------------------------------------------- decode kernel differential --
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("factor", FACTORS)
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_decode_kernel_matches_executor(case, factor, mode):
+    """The Pallas decode kernel (interpret mode) against the reference
+    executor and the numpy gold, across GQA groups 1/2/4 and one, two or
+    four KV tiles.  The grid has one point per (row, KV head, KV tile) —
+    times the pump axis where mode R runs each sub-tile's own sweep."""
+    c = DECODE_CASES[case]
+    kern = run_case(c, factor, mode, "pallas", pallas_mode="interpret")
+    [region] = kern.report.emission.values()
+    assert region["tier"] == "pallas"
+    b, h, t, d = c.args
+    hkv = c.kwargs["hkv"]
+    bkv = c.kwargs["bkv"] or decode_kv_tile(t, d, h // hkv)
+    points = int(np.prod([e for _s, e in region["grid"]]))
+    assert points == b * hkv * (t // bkv) \
+        * (region["pump"] if mode == "R" else 1)
+
+
+def test_decode_plan_reports_grid_and_tile_and_counts_grid_points():
+    """``PlanRegistry.plans()`` shows a decode plan's KV tile and grid, and
+    emitting the kernel counts its grid's points under
+    ``emission.grid_points``, so a trace's kernel time divides into time
+    per grid step."""
+    from repro import obs
+    reg = PlanRegistry(pump=1, cache=False)
+    before = obs.snapshot()["counters"].get("emission.grid_points", 0)
+    q = _ints((2, 8, 8), 1)                   # 8 query heads over 2 KV heads
+    kv = _ints((2, 2, 64, 8), 2)
+    reg.decode_attention(q, kv, kv, jnp.asarray([40, 63], jnp.int32))
+    [plan] = [pl for pl in reg.plans() if pl["kernel"] == "decode_attention"]
+    assert plan["bkv"] == decode_kv_tile(64, 8, 4, 4) == 32
+    assert plan["grid"] == [["bi", 2], ["kvh", 2], ["ji", 2]]
+    after = obs.snapshot()["counters"]["emission.grid_points"]
+    assert after - before == 2 * 2 * 2
+
+
+@pytest.mark.parametrize("t, d, group, itemsize, want", [
+    (1024, 128, 2, 2, 512),     # qwen3-0.6b's served cache: two tiles
+    (1024, 64, 4, 2, 512),      # granite-3-2b's
+    (8192, 128, 2, 2, 1024),    # long caches stop at the VMEM share
+    (16, 8, 1, 4, 8),           # the smallest bucket keeps two tiles
+    (8, 8, 1, 4, 8),            # below two sublane tiles: one tile
+    (48, 8, 2, 4, 8),           # the largest power of two dividing t / 2
+])
+def test_decode_kv_tile_rule(t, d, group, itemsize, want):
+    assert decode_kv_tile(t, d, group, itemsize) == want
 
 
 # ----------------------------------------------------- decode parity sweep --

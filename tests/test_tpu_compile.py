@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import os
 
+import numpy as np
 import pytest
 
 import jax
@@ -44,6 +45,15 @@ CASES = {
     "decode_attention": ("decode_attention", (4, QWEN3["h"], 2048, QWEN3["d"]),
                          dict(bkv=128, hkv=QWEN3["hkv"], dtype="bfloat16",
                               itemsize=2)),
+    # the served decode steps: 32 slots over a 1024-slot cache, the graph's
+    # own KV tile; Qwen3-0.6B's heads, then Granite-3.0-2B's (32 query heads
+    # over 8 KV heads of 64)
+    "decode_attention-qwen3": ("decode_attention",
+                               (32, QWEN3["h"], 1024, QWEN3["d"]),
+                               dict(hkv=QWEN3["hkv"], dtype="bfloat16",
+                                    itemsize=2)),
+    "decode_attention-granite": ("decode_attention", (32, 32, 1024, 64),
+                                 dict(hkv=8, dtype="bfloat16", itemsize=2)),
     "vecadd": ("vecadd", (1 << 20,), dict(vector_width=1024)),
     "matmul": ("matmul", (1024, 1024, 1024), {}),
 }
@@ -135,3 +145,32 @@ def test_lowered_kernel_is_named_in_the_chip_module(topo, one_chip,
     calls = [ln.split(" = ", 1)[0].strip() for ln in text.splitlines()
              if "tpu_custom_call" in ln]
     assert calls and all(c.startswith(f"%{name}_m2T") for c in calls)
+
+
+@pytest.mark.parametrize("factor", (1, 2))
+@pytest.mark.parametrize("kernel", ["decode_attention-qwen3",
+                                    "decode_attention-granite"])
+def test_served_decode_grid_steps_per_kv_head_and_wide_tile(kernel, factor):
+    """The served decode kernel takes one grid step per (slot, KV head, KV
+    tile), the query heads of a KV head in one block and a tile of the
+    graph's own width: b × hkv × (t / bkv) points (mode T splits the tile
+    axis into the pump axis without adding any), not one per query head and
+    128 keys."""
+    name, (b, h, t, d), kwargs = CASES[kernel]
+    g, est = BUILDERS[name](b, h, t, d, **kwargs)
+    graph, _report = Pipeline.default(factor=factor, mode="T",
+                                      estimate=est).run(g)
+    [region] = partition_regions(graph)
+    notes = []
+    plan = plan_region(graph, region, notes.append)
+    assert plan is not None and plan.pallas_ok and not notes, notes
+    assert tpu_tiling_ok(graph, plan)
+    bkv = graph.nodes["k"].shape[2] // 2      # the cache in two tiles
+    hkv = kwargs["hkv"]
+    want = (("bi", b), ("kvh", hkv), ("ji", t // bkv // factor))
+    assert plan.grid == want + ((("_pump", factor),) if factor > 1 else ())
+    points = b * hkv * (t // bkv)
+    assert np.prod([e for _s, e in plan.grid]) == points
+    # fewer than one per (slot, query head, 128 keys) by the GQA group
+    # times the tile's width over 128
+    assert points * (h // hkv) * (bkv // 128) == b * h * (t // 128)
