@@ -9,5 +9,7 @@ Everything a cell needs is data found by name: ``BENCHMARK.json`` lists the
 cells and metrics, ``bench/configs/<config>.json`` holds a model
 configuration as it is run, ``bench/traffic/<traffic>.json`` a traffic mix,
 ``bench/workloads/<cell>.json`` the serving settings and load of one cell,
-and ``bench/metrics/<metric>.py`` the reader of one metric.
+``bench/families/<family>.py`` every seam that depends on the model's
+architecture, for the family a configuration file names under
+``family``, and ``bench/metrics/<metric>.py`` the reader of one metric.
 """

@@ -2,13 +2,14 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the finished requests, drawn from the seed and always holding the one with
-the most output tokens, is run once through the float32 reference
-(``bench.reference.dense``): each prompt followed by its served tokens.  For
-every served token the reference gives the gap by which that token's logit
-lies below the reference's best logit at its position.  The program serves
-greedy tokens, so a sound run serves near-best tokens and its widest gap is
-small; a wrong token, a stale cache, or a lower precision shows as a wide
-one.  The widest gap over the sample is held to the cell's ``gap_limit``.
+the most output tokens, is run once through the float32 reference of the
+model's family (its ``reference`` module, ``bench/families``): each prompt
+followed by its served tokens.  For every served token the reference gives
+the gap by which that token's logit lies below the reference's best logit
+at its position.  The program serves greedy tokens, so a sound run serves
+near-best tokens and its widest gap is small; a wrong token, a stale cache,
+or a lower precision shows as a wide one.  The widest gap over the sample
+is held to the cell's ``gap_limit``.
 
 The control (``control_gaps``) puts the reference computed in float8 in the
 program's place: at each of the same positions the token the float8
@@ -61,27 +62,29 @@ def _rows(picked, max_len: int):
     return toks, query, mask
 
 
-def served_gaps(params, cfg: Dict, picked, max_len: int) -> np.ndarray:
+def served_gaps(reference, params, cfg: Dict, picked,
+                max_len: int) -> np.ndarray:
     """Gap of every served token in ``picked`` (flattened)."""
-    from bench.reference import dense
     toks, query, mask = _rows(picked, max_len)
     out = []
     for b in range(0, len(toks), ROWS):
-        g, _ = dense.gaps(params, cfg, toks[b:b + ROWS], query[b:b + ROWS])
+        g, _ = reference.gaps(params, cfg, toks[b:b + ROWS],
+                              query[b:b + ROWS])
         out.append(np.asarray(g)[..., 0][mask[b:b + ROWS]])
     return np.concatenate(out) if out else np.zeros(0, np.float32)
 
 
-def control_gaps(params, cfg: Dict, picked, max_len: int) -> np.ndarray:
+def control_gaps(reference, params, cfg: Dict, picked,
+                 max_len: int) -> np.ndarray:
     """Gap, in the float32 reference, of the token the float8 reference
     ranks first at each served position."""
-    from bench.reference import dense
     toks, query, mask = _rows(picked, max_len)
     out = []
     for b in range(0, len(toks), ROWS):
         t = toks[b:b + ROWS]
-        _, top8 = dense.gaps(params, cfg, t, query[b:b + ROWS], quant="fp8")
-        g, _ = dense.gaps(params, cfg, t, np.asarray(top8)[..., None])
+        _, top8 = reference.gaps(params, cfg, t, query[b:b + ROWS],
+                                 quant="fp8")
+        g, _ = reference.gaps(params, cfg, t, np.asarray(top8)[..., None])
         out.append(np.asarray(g)[..., 0][mask[b:b + ROWS]])
     return np.concatenate(out) if out else np.zeros(0, np.float32)
 

@@ -12,8 +12,9 @@ when ``run_step`` returns.
 Around the calls into the program it records its own spans, as
 ``jax.profiler.TraceAnnotation`` (``bench.submit``, ``bench.sleep``,
 ``bench.run_step``, ``bench.prefill``, ``bench.decode``,
-``bench.host_sampling``), and keeps per step the work the step needed
-(``bench.counts``) and its host-clock duration.
+``bench.host_sampling``), and keeps per step the work the step needed (the
+model family's work functions, ``bench/families``) and its host-clock
+duration.
 
 Requests beyond ``queue_cap`` waiting inside the scheduler stay with the
 client until there is room, their due time unchanged.  The program's
@@ -37,8 +38,6 @@ import time
 from typing import Callable, Dict, List, Optional
 
 import jax
-
-from bench import counts
 
 
 def annotate(name: str):
@@ -137,10 +136,10 @@ class Probe:
 class Client:
     """One run's client side: the traffic, the scheduler, the records."""
 
-    def __init__(self, sched, eng, gen, model: counts.Model,
+    def __init__(self, sched, eng, gen, family, model,
                  queue_cap: int, clock: Callable[[], float]):
         self.sched, self.eng, self.gen = sched, eng, gen
-        self.model = model
+        self.family, self.model = family, model
         self.queue_cap = queue_cap
         self.clock = clock                      # seconds, window opens at 0
         self.probe = Probe(eng, sched, clock)
@@ -237,16 +236,16 @@ class Client:
                 self.gen.complete(rec.spec, t_end)
         self._check_hooks(sum(admitted.values()), n_later,
                           counts_before, counts_after)
-        m = self.model
+        fam, m = self.family, self.model
         for p in probe.step_prefills:
             s = p.real[0]
             p.real = [s] * admitted.get(s, 0)
-            p.work = counts.prefill_step(m, p.real)
-            p.attn = counts.prefill_attention(m, p.real)
+            p.work = fam.prefill_step(m, p.real)
+            p.attn = fam.prefill_attention(m, p.real)
             self.steps.append(p)
         if dec is not None:
-            dec.work = counts.decode_step(m, dec.real)
-            dec.attn = counts.decode_attention(m, dec.real)
+            dec.work = fam.decode_step(m, dec.real)
+            dec.attn = fam.decode_attention(m, dec.real)
             self.steps.append(dec)
 
     def _check_hooks(self, n_first: int, n_later: int, before: Dict,

@@ -1,5 +1,6 @@
-"""Plain float32 reference of the dense decoder family the benchmark serves.
+"""Plain float32 references, one module per model family (``bench/families``
+names its own as ``reference``).
 
-It imports nothing of the program under test: it reads a configuration
+They import nothing of the program under test: each reads a configuration
 file's keys and a parameter tree laid out as ``bench.weights`` makes it.
 """

@@ -63,8 +63,8 @@ class NoProgram(RuntimeError):
 # ------------------------------------------------------------------ cell --
 def load_cell(root: Path, name: str) -> Dict:
     """Everything one cell needs, found by the names ``BENCHMARK.json``
-    gives: its entry, configuration, traffic mix, serving settings and the
-    metrics it reports."""
+    gives: its entry, configuration and its family's module, traffic mix,
+    serving settings and the metrics it reports."""
     with open(root / "BENCHMARK.json") as f:
         bench = json.load(f)
     entry = next((w for w in bench["workloads"] if w["name"] == name), None)
@@ -81,16 +81,32 @@ def load_cell(root: Path, name: str) -> Dict:
         return [m for m in metrics
                 if "workloads" not in m or name in m["workloads"]]
 
+    cj = load(root / config["file"])
+    if "family" not in cj:
+        raise KeyError(f"{config['file']} names no family")
     return {
         "name": name,
         "chips": entry["chips"],
-        "config": load(root / config["file"]),
+        "config": cj,
+        "family": load_family(here / "families", cj["family"]),
         "traffic": load(here / "traffic" / f"{entry['traffic']}.json"),
         "workload": load(here / "workloads" / f"{name}.json"),
         "end_to_end": mine(bench["end_to_end"]),
         "per_layer": mine(bench["per_layer"]),
         "metrics_dir": here / "metrics",
     }
+
+
+def load_family(families_dir: Path, name: str):
+    """The module of one model family (``bench/families/<name>.py``)."""
+    path = families_dir / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no family {name!r}: {path} does not exist")
+    spec = importlib.util.spec_from_file_location(f"bench_family_{name}",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def read_metric(metrics_dir: Path, name: str, run) -> Optional[float]:
@@ -138,21 +154,6 @@ def enable_caches(root: Path) -> None:
 
 
 # --------------------------------------------------------------- program --
-def model_config(cj: Dict):
-    """The program's configuration object for a configuration file."""
-    from repro.configs.base import ModelConfig
-    h, d = cj["num_attention_heads"], cj["hidden_size"]
-    return ModelConfig(
-        name=cj["name"], family="dense", n_layers=cj["num_hidden_layers"],
-        d_model=d, n_heads=h, n_kv_heads=cj["num_key_value_heads"],
-        d_ff=cj["intermediate_size"], vocab_size=cj["vocab_size"],
-        head_dim=cj.get("head_dim") or d // h, qk_norm=bool(cj["qk_norm"]),
-        tie_embeddings=bool(cj["tie_word_embeddings"]),
-        rope_theta=float(cj["rope_theta"]),
-        norm_eps=float(cj["rms_norm_eps"]), attention_impl="pallas",
-        kernel_plan="measure", dtype="bfloat16")
-
-
 class CompileCounter:
     """Counts JAX compilations: backend compiles and loads from the
     persistent cache."""
@@ -212,17 +213,18 @@ class Server:
 
     def __init__(self, cell: Dict, seed: int, log):
         import jax
-        from bench import counts, weights
+        from bench import weights
         from repro.serve.engine import Engine, ServeConfig
         self.cell, self.log = cell, log
-        cj, mix = cell["config"], cell["traffic"]
+        cj, mix, fam = cell["config"], cell["traffic"], cell["family"]
         sv = self.sv = cell["workload"]["serve"]
         seed32 = int(seed) % 2**31
-        self.cfg = model_config(cj)
-        self.model = counts.Model.from_config(cj)
+        self.cfg = fam.program_config(cj)
+        self.model = fam.work_model(cj)
         self.compiles = CompileCounter()
         t0 = time.perf_counter()
-        self.params = jax.block_until_ready(weights.make(cj, seed32))
+        self.params = jax.block_until_ready(
+            weights.make(fam.weight_shapes(cj), seed32))
         t1 = time.perf_counter()
         # the engine's mesh holds the cell's chips and no more devices
         from jax.sharding import AxisType
@@ -240,14 +242,9 @@ class Server:
             from bench.harness import ProbeLost
             raise ProbeLost("the engine keeps no plan registry (_reg) under "
                             "kernel_plan='measure'")
-        if sv["max_slots"] != sv["engine_batch"]:
-            # the decode step runs over all slots: its plan bucket is keyed
-            # on the slot count, which the engine's warmup (at its batch)
-            # does not cover
-            self.reg.warmup([("decode_attention", dict(
-                b=sv["max_slots"], h=self.cfg.n_heads,
-                hkv=self.cfg.n_kv_heads, t=sv["max_len"],
-                d=self.cfg.head_dim_, dtype="bfloat16"))])
+        plans = fam.warm_plans(self.cfg, sv)
+        if plans:
+            self.reg.warmup(plans)
         t2 = time.perf_counter()
         self.sched = self.new_scheduler()
         warm_shapes(self.sched, self.eng, list(mix["prompt_lens"]))
@@ -272,8 +269,10 @@ class Server:
             self.params = self.eng.params = None
         gc.collect()
         if seed is not None:
+            cj = self.cell["config"]
             self.params = self.eng.params = jax.block_until_ready(
-                weights.make(self.cell["config"], int(seed) % 2**31))
+                weights.make(self.cell["family"].weight_shapes(cj),
+                             int(seed) % 2**31))
         self.sched = self.new_scheduler()
 
 
@@ -304,7 +303,7 @@ def serve(server: Server, seed: int, seconds: float, trace: bool,
     compiles = server.compiles
     clock0 = time.perf_counter() + float(mix.get("preroll_s", 0.0))
     gen = generator.make(mix, wl["load"], seed, cj["vocab_size"], seconds)
-    drv = Client(sched, eng, gen, server.model,
+    drv = Client(sched, eng, gen, cell["family"], server.model,
                  queue_cap=server.sv["engine_batch"],
                  clock=lambda: time.perf_counter() - clock0)
     snap: Dict = {}
@@ -323,24 +322,29 @@ def serve(server: Server, seed: int, seconds: float, trace: bool,
                     compiles=compiles.n)
 
     try:
-        drv.run(seconds, on_open=on_open)
+        try:
+            drv.run(seconds, on_open=on_open)
+        finally:
+            snap["compiles_end"] = compiles.n
+            if "ann" in snap:
+                snap["ann"].__exit__(None, None, None)
+        end = engine_counts(eng)
+        counters = {
+            "decode_count": end["decode"][0] - snap["decode"][0],
+            "decode_total_s": end["decode"][1] - snap["decode"][1],
+            "prefill_count": end["prefill"][0] - snap["prefill"][0],
+            "prefill_total_s": end["prefill"][1] - snap["prefill"][1],
+            "plan_misses": reg.stats.misses - snap["misses"],
+            "fallbacks": reg.stats.fallbacks - snap["fallbacks"],
+            "compiles": snap["compiles_end"] - snap["compiles"],
+        }
+        # the requests due in the window are served before the profiler
+        # stops: writing a large trace can take as long as the wait for
+        # their first tokens (the reduction reads only ``bench.window``)
+        gave_up_at = drv.finish(seconds, FIRST_TOKEN_WAIT_S)
     finally:
-        snap["compiles_end"] = compiles.n
-        if "ann" in snap:
-            snap["ann"].__exit__(None, None, None)
         if trace:
             jax.profiler.stop_trace()
-    end = engine_counts(eng)
-    counters = {
-        "decode_count": end["decode"][0] - snap["decode"][0],
-        "decode_total_s": end["decode"][1] - snap["decode"][1],
-        "prefill_count": end["prefill"][0] - snap["prefill"][0],
-        "prefill_total_s": end["prefill"][1] - snap["prefill"][1],
-        "plan_misses": reg.stats.misses - snap["misses"],
-        "fallbacks": reg.stats.fallbacks - snap["fallbacks"],
-        "compiles": snap["compiles_end"] - snap["compiles"],
-    }
-    gave_up_at = drv.finish(seconds, FIRST_TOKEN_WAIT_S)
     stats = jax.devices()[0].memory_stats() or {}
     late = np.asarray(drv.lateness or [0.0]) * 1e3
     log(f"window closed: {len(drv.stamps)} tokens stamped, {len(drv.reqs)} "
@@ -403,13 +407,14 @@ def check(cell: Dict, params, done, seed: int, log, control: bool = False):
     picked = correctness.sample(done, seed, cc["sample_requests"],
                                 cc["sample_tokens"])
     t0 = time.perf_counter()
-    gaps = correctness.served_gaps(params, cj, picked, max_len)
+    ref = cell["family"].reference
+    gaps = correctness.served_gaps(ref, params, cj, picked, max_len)
     log(f"reference over {len(picked)} requests, {gaps.size} served tokens "
         f"in {time.perf_counter() - t0:.1f} s")
     chk = correctness.checks(gaps, cc["gap_limit"])
     if not control:
         return chk, None
-    ctrl = correctness.control_gaps(params, cj, picked, max_len)
+    ctrl = correctness.control_gaps(ref, params, cj, picked, max_len)
     return chk, correctness.checks(ctrl, cc["gap_limit"])
 
 

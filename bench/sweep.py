@@ -62,7 +62,7 @@ def main(argv=None) -> int:
     agree = {rate: True for rate in rates}
     for seed in seeds:
         for rate in rates:
-            c = copy.deepcopy(cell)
+            c = dict(cell, workload=copy.deepcopy(cell["workload"]))
             c["workload"]["load"]["rate_rps"] = rate
             server.cell = c
             served = bench_run.serve(server, seed, s, False, None,

@@ -1,4 +1,4 @@
-"""Small configurations of the benchmark's two model families, for tests."""
+"""Small configurations of the benchmark's model families, for tests."""
 from __future__ import annotations
 
 import json
@@ -6,21 +6,18 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[2]
 
-# the smoke sizes of the program's own qwen3/granite presets
-SMOKE = dict(num_hidden_layers=2, hidden_size=64, num_attention_heads=4,
-             num_key_value_heads=2, intermediate_size=128, vocab_size=256)
+
+def family(name: str = "dense"):
+    """The module of one model family, loaded as a cell loads it."""
+    from bench import run as bench_run
+    return bench_run.load_family(ROOT / "bench" / "families", name)
 
 
 def smoke_config(name: str) -> dict:
-    """A benchmark configuration file's keys at the smoke size."""
+    """A benchmark configuration file's keys at its family's smoke size."""
     with open(ROOT / "bench" / "configs" / f"{name}.json") as f:
         cj = json.load(f)
-    cj.update(SMOKE, name=f"{name}-smoke")
-    if cj.get("head_dim"):
-        cj["head_dim"] = 32
-    return cj
-
-
+    return family(cj["family"]).smoke(cj)
 
 
 def smoke_cell(loop: str = "closed", gap_limit: float = 0.02) -> dict:
@@ -42,6 +39,7 @@ def smoke_cell(loop: str = "closed", gap_limit: float = 0.02) -> dict:
         bench = json.load(f)
     return {
         "name": "smoke", "chips": 1, "config": smoke_config("qwen3-0.6b"),
+        "family": family(),
         "traffic": mix,
         "workload": {"config": "smoke", "traffic": loop, "load": load,
                      "serve": {"max_slots": 4, "engine_batch": 2,

@@ -7,14 +7,15 @@ import json
 import pytest
 
 from bench import counts, peaks
-from bench.run import model_config
-from bench_helpers import ROOT
+from bench_helpers import ROOT, family
+
+DENSE = family("dense")
 
 
 def _model(name):
     with open(ROOT / "bench" / "configs" / f"{name}.json") as f:
         cj = json.load(f)
-    return cj, counts.Model.from_config(cj)
+    return cj, DENSE.work_model(cj)
 
 
 @pytest.mark.parametrize("name,params_b,kv_kib", [
@@ -25,7 +26,7 @@ def test_model_sizes(name, params_b, kv_kib):
     assert abs(total / 1e9 - params_b) < 0.001
     assert m.kv_bytes_per_token == kv_kib * 1024
     # the program's own count agrees (norm scales aside)
-    prog = model_config(cj).param_count()
+    prog = DENSE.program_config(cj).param_count()
     assert abs(prog - total) / total < 1e-3
 
 

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from bench import correctness, run as bench_run, weights
-from bench_helpers import ROOT, smoke_cell, smoke_config
+from bench_helpers import ROOT, family, smoke_cell, smoke_config
 
 # the smoke cell's limit: the program reads 0 at this size, every fault and
 # the control read far above it
@@ -64,13 +64,15 @@ def test_decode_step_returning_its_cache_unchanged_is_not_correct(
 @pytest.mark.parametrize("seed", [1, 2, 3])
 def test_float8_control_fails_the_limit(seed):
     cj = smoke_config("qwen3-0.6b")
-    params = weights.make(cj, seed)
+    dense = family("dense")
+    params = weights.make(dense.weight_shapes(cj), seed)
     rng = np.random.default_rng(seed)
     picked = []
     for rid in range(4):
         prompt = rng.integers(0, cj["vocab_size"], 16).astype(np.int32)
         served = rng.integers(0, cj["vocab_size"], 24).astype(np.int32)
         picked.append((rid, prompt, served))
-    gaps = correctness.control_gaps(params, cj, picked, 64)
+    gaps = correctness.control_gaps(dense.reference, params, cj, picked,
+                                    64)
     chk = correctness.checks(gaps, LIMIT)
     assert not correctness.passed(chk)

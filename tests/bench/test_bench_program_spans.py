@@ -21,7 +21,7 @@ from types import SimpleNamespace
 
 import pytest
 
-from bench import counts, peaks, program_spans, trace
+from bench import peaks, program_spans, trace
 from bench import run as bench_run
 from bench.harness import StepRecord
 from bench_helpers import smoke_cell
@@ -148,11 +148,13 @@ def test_a_trace_without_program_spans_reads_nothing():
 def test_decode_roofline_reads_the_named_kernel(path, found):
     """The reader finds the decode kernel by its name, and finds nothing
     in a trace whose kernels are unnamed."""
-    cj = dict(smoke_cell()["config"], num_hidden_layers=2, hidden_size=1024,
+    cell = smoke_cell()
+    fam = cell["family"]
+    cj = dict(cell["config"], num_hidden_layers=2, hidden_size=1024,
               num_attention_heads=16, num_key_value_heads=8, head_dim=128)
-    m = counts.Model.from_config(cj)
+    m = fam.work_model(cj)
     step = StepRecord("decode", 0.0, 0.001, rows=4, real=[140] * 4,
-                      attn=counts.decode_attention(m, [140] * 4))
+                      attn=fam.decode_attention(m, [140] * 4))
     run = SimpleNamespace(trace=trace.reduce(str(path)),
                           peaks=peaks.peaks("TPU v5 lite"), model=m,
                           window_steps=[step])

@@ -63,6 +63,37 @@ def test_open_loop_reports_ttft(serving_env):
     assert p90 > 0
 
 
+def test_the_window_is_drained_before_the_profiler_stops(serving_env,
+                                                         monkeypatch,
+                                                         tmp_path):
+    """Writing a large trace can outlast the wait for first tokens, so the
+    requests due in the window are served before the profiler stops."""
+    import jax
+    from bench import harness, trace as trace_mod
+    order = []
+    finish = harness.Client.finish
+
+    def recorded_finish(self, *args):
+        out = finish(self, *args)
+        order.append("finished")
+        return out
+
+    monkeypatch.setattr(harness.Client, "finish", recorded_finish)
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda *_a, **_k: order.append("start"))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: order.append("stop"))
+    xplane = tmp_path / "t.xplane.pb"
+    xplane.write_bytes(b"")
+    monkeypatch.setattr(trace_mod, "find", lambda _d: str(xplane))
+    monkeypatch.setattr(trace_mod, "reduce", lambda _p: {})
+    server = bench_run.Server(smoke_cell("open"), 4, lambda _m: None)
+    served = bench_run.serve(server, 4, 1.5, True, tmp_path,
+                             time.perf_counter())
+    assert order == ["start", "finished", "stop"]
+    assert served.trace == {}
+
+
 def _skip_decode_hook(monkeypatch):
     """The scheduler decodes through the engine's class method, past the
     instance attribute the probe wraps."""
